@@ -21,6 +21,9 @@ _SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 def _run8(code: str) -> subprocess.CompletedProcess:
     env = dict(os.environ)
+    # Virtual CPU devices only: a child must never reach for the chip the
+    # parent process may already hold.
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = _SRC + os.pathsep + env.get("PYTHONPATH", "")
     return subprocess.run([sys.executable, "-c", textwrap.dedent(code)],

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import sys
 
 
@@ -284,6 +285,11 @@ def main(argv: list[str] | None = None) -> None:
                          "--check-regression (default 1.0 = 2x); negative "
                          "disables wall-clock comparison")
     args = ap.parse_args(argv)
+
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
 
     if args.check_regression:
         tol = None if args.tol_time < 0 else args.tol_time

@@ -39,8 +39,8 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..compat import shard_map
 
 __all__ = ["gemt3_shardmap", "gemt3_auto", "tensor_spec"]
 
@@ -108,8 +108,13 @@ def gemt3_shardmap(
     unlocking the sharded cost-model order search.  ``engine_kwargs``
     (``use_pallas``, ``fuse``, ``autotune``, ``batch_axis``, …) pass
     through to :func:`repro.engine.gemt3_planned`.  ``engine=False`` is
-    the original einsum-only schedule (benchmark baseline).
+    the original einsum-only schedule (benchmark baseline).  Both return
+    arrays sharded on the Auto-axis form of ``mesh``, so their results
+    combine with each other and with :func:`gemt3_auto`'s.
     """
+    from ..launch.mesh import auto_axes
+
+    mesh = auto_axes(mesh)
     if engine:
         from ..engine import gemt3_planned as _planned
 
@@ -149,6 +154,9 @@ def gemt3_auto(
     order: Sequence[int] = (3, 1, 2),
 ):
     """GSPMD baseline: same stationary-spec pinning, XLA picks collectives."""
+    from ..launch.mesh import auto_axes
+
+    mesh = auto_axes(mesh)  # with_sharding_constraint needs Auto axes
     spec = tensor_spec(axes)
 
     def f(x, c1, c2, c3):

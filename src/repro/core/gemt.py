@@ -181,10 +181,10 @@ def dxt3d(
 
     ``engine=True`` routes through the planned execution engine
     (``repro.engine``): the stage order is chosen by the cost model (the
-    ``order`` argument is ignored) and each stage runs on the Pallas kernel
-    dispatch; ``engine_kwargs`` (e.g. ``autotune=True``, or
-    ``differentiable=True`` for a ``jax.grad``-safe engine-lowered
-    backward pass) pass through.
+    ``order`` argument is ignored), each stage runs on the Pallas kernel
+    dispatch, and ``x`` may carry a leading batch axis; ``engine_kwargs``
+    (e.g. ``autotune=True``, ``with_info=True``, or ``differentiable=True``
+    for a ``jax.grad``-safe engine-lowered backward pass) pass through.
     """
     from ..obs import trace as _trace
     from .transforms import coefficient_matrix, inverse_coefficient_matrix
@@ -196,7 +196,7 @@ def dxt3d(
                           "engine": bool(engine), "shape": tuple(x.shape)})
     with sp:
         build = inverse_coefficient_matrix if inverse else coefficient_matrix
-        n1, n2, n3 = x.shape
+        n1, n2, n3 = x.shape[-3:] if engine else x.shape
         c1, c2, c3 = build(kind, n1), build(kind, n2), build(kind, n3)
         if jnp.iscomplexobj(c1) and not jnp.iscomplexobj(x):
             x = x.astype(c1.dtype)

@@ -237,10 +237,19 @@ def autotune_gemm(
     Returns the best block sizes; a cache hit skips all measurement.
     ``adjoint`` selects the backward tuning role (its own cache entries —
     see :func:`make_key`); ``accum`` keys and measures the guarded
-    accumulation mode's dispatch.
+    accumulation mode's dispatch.  Every tile is a lane dim in one of the
+    forward/backward dispatches, so candidates that break
+    :func:`~repro.engine.plan.lane_tile_ok` are never probed or returned.
     """
+    from .plan import _lane_tile, lane_tile_ok
+
     m, kdim = x.shape
     n = c.shape[1]
+    dims = (m, n, kdim)
+
+    def legal(cfg):
+        return all(lane_tile_ok(t, d) for t, d in zip(cfg, dims))
+
     cache = cache if cache is not None else AutotuneCache()
     key = make_key(m, n, kdim, x.dtype, kind, sig, adjoint=adjoint,
                    accum=accum)
@@ -249,17 +258,19 @@ def autotune_gemm(
     # An untuned entry (defaults recorded off-TPU) must not suppress real
     # tuning once the cache file reaches a host where the knobs matter.
     if hit is not None and (hit.get("tuned", True) or not knobs_live):
-        return int(hit["bm"]), int(hit["bn"]), int(hit["bk"])
+        cfg = (int(hit["bm"]), int(hit["bn"]), int(hit["bk"]))
+        if legal(cfg):
+            return cfg
 
     lo, _hi = _BOUNDS
-    caps = tuple(max(lo, _pow2_floor(d)) for d in (m, n, kdim))
+    caps = tuple(max(lo, _pow2_floor(d)) for d in dims)
 
     if not knobs_live:
         # The reference paths ignore bm/bn/bk, so timing candidates here
         # would hill-climb on pure noise and persist a meaningless winner.
-        # Cache the clamped defaults instead (still shape-correct for the
+        # Cache the planner's defaults instead (still shape-correct for the
         # Pallas path if this cache later reaches a TPU host).
-        cfg = tuple(min(128, cap) for cap in caps)
+        cfg = tuple(_lane_tile(d) for d in dims)
         cache.put(key, {"bm": cfg[0], "bn": cfg[1], "bk": cfg[2],
                         "us": 0.0, "kind": kind, "tuned": False})
         try:
@@ -286,11 +297,13 @@ def autotune_gemm(
         with sp:
             return _time_us(call, reps=reps)
 
-    cur = tuple(min(128, cap) for cap in caps)
+    cur = tuple(_lane_tile(d) for d in dims)
     cur_us = measure(cur)
     for _ in range(max_steps):
         moved = False
         for cand in _neighbors(cur, caps):
+            if not legal(cand):
+                continue
             us = measure(cand)
             if us < cur_us * (1.0 - _MIN_GAIN):
                 cur, cur_us, moved = cand, us, True
@@ -331,9 +344,10 @@ def autotune_fused(
     (VMEM-feasible) choice; every candidate is re-checked against the
     footprint model so tuning can never climb out of the budget.
     ``bna``/``kbp`` stay pinned (Kb is not grid-blocked and the na tile
-    only trades partial-width for step count).
+    only trades partial-width for step count); ``bka`` candidates must
+    obey :func:`~repro.engine.plan.lane_tile_ok`.
     """
-    from .plan import DEFAULT_VMEM_BUDGET, fused_vmem_bytes
+    from .plan import DEFAULT_VMEM_BUDGET, fused_vmem_bytes, lane_tile_ok
 
     u = int(rows)
     na, ka = ca.shape
@@ -351,8 +365,9 @@ def autotune_fused(
     caps = tuple(max(lo, _pow2_floor(d)) for d in (u, ka, nb))
 
     def fits(cfg):
-        return fused_vmem_bytes(cfg[0], cfg[1], cfg[2], bna, kbp,
-                                isz, accum) <= budget
+        return (lane_tile_ok(cfg[1], ka)
+                and fused_vmem_bytes(cfg[0], cfg[1], cfg[2], bna, kbp,
+                                     isz, accum) <= budget)
 
     knobs_live = use_pallas is True or ops.on_tpu()
     hit = cache.get(key)
@@ -437,9 +452,10 @@ def autotune_fused3(
     re-checked against the footprint model so tuning can never climb out
     of the budget.  ``bna``/``kbp``/``kcp`` stay pinned (Kb/Kc are not
     grid-blocked and the na tile only trades partial-width for step
-    count).
+    count); ``bka`` candidates must obey
+    :func:`~repro.engine.plan.lane_tile_ok`.
     """
-    from .plan import DEFAULT_VMEM_BUDGET, fused3_vmem_bytes
+    from .plan import DEFAULT_VMEM_BUDGET, fused3_vmem_bytes, lane_tile_ok
 
     u = int(rows)
     na, ka = ca.shape
@@ -455,8 +471,9 @@ def autotune_fused3(
     caps = tuple(max(lo, _pow2_floor(d)) for d in (u, ka, nb, nc))
 
     def fits(cfg):
-        return fused3_vmem_bytes(cfg[0], cfg[1], cfg[2], cfg[3], bna, kbp,
-                                 kcp, isz, accum) <= budget
+        return (lane_tile_ok(cfg[1], ka)
+                and fused3_vmem_bytes(cfg[0], cfg[1], cfg[2], cfg[3], bna,
+                                      kbp, kcp, isz, accum) <= budget)
 
     knobs_live = use_pallas is True or ops.on_tpu()
     hit = cache.get(key)

@@ -30,9 +30,9 @@ import hashlib
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..kernels import ops
 from ..kernels.ops import _memo_sink
 from ..memo import ArrayMemo
@@ -814,6 +814,14 @@ def _execute_vjp_composed(plan: GemtPlan, adj: GemtPlan,
         elif _kernels_live(use_pallas, cts[m0], cts[m1]):
             t2 = chain.tiles
             adj_plan = ops.esop_plan_cached(cts[m0], t2[3], t2[1])
+        # Staged ESOP stages inside the jit see tracer coefficients, which
+        # have no host-readable block schedule: build them here, as the
+        # sharded program does.
+        rec_esop = {st.mode: ops.esop_plan_cached(cs[st.mode], st.bk, st.bn)
+                    for st in plan.stages[:-1] if st.backend == "esop"}
+        tail = adj.stages[2]
+        tail_esop = (ops.esop_plan_cached(cts[tail.mode], tail.bk, tail.bn)
+                     if tail.backend == "esop" else None)
         infos_cell: list = []
 
         def walk_body(x_, g_, c1_, c2_, c3_, t1_, t2_, t3_):
@@ -833,7 +841,8 @@ def _execute_vjp_composed(plan: GemtPlan, adj: GemtPlan,
                 ys, y = [x_], x_
                 for st in plan.stages[:-1]:
                     y, si = lower_stage(y, csd[st.mode], st,
-                                        use_pallas=use_pallas)
+                                        use_pallas=use_pallas,
+                                        esop_plan=rec_esop.get(st.mode))
                     infos.append(dict(si, kind="grad_recompute"))
                     ys.append(y)
             if chain.depth == 3:
@@ -850,9 +859,9 @@ def _execute_vjp_composed(plan: GemtPlan, adj: GemtPlan,
                 infos.append({"kind": "grad_x", "backend": "fused",
                               "modes": chain.modes[:2],
                               "vmem_bytes": chain.vmem_bytes})
-                st = adj.stages[2]
-                dx, si = lower_stage(g2, ctd[st.mode], st,
-                                     use_pallas=use_pallas)
+                dx, si = lower_stage(g2, ctd[tail.mode], tail,
+                                     use_pallas=use_pallas,
+                                     esop_plan=tail_esop)
                 infos.append(dict(si, kind="grad_chain"))
             dcl = lower_coeff_grad_batch(ys, [g2, g1, g_], plan.order,
                                          use_pallas=use_pallas)
@@ -1537,8 +1546,14 @@ def gemt3_planned(
     SR-GEMM updates.  ``info`` gains ``grad_*`` fields describing the
     planned backward; ``grad_stats()`` counts executed backward passes.
     """
-    if mesh is not None and axes is None:
-        axes = default_mode_axes(mesh, batch_axis)
+    if mesh is not None:
+        from ..launch.mesh import auto_axes
+
+        # The schedule places its own collectives; on Auto axes its output
+        # stays an ordinary sharded array that callers may reshape freely.
+        mesh = auto_axes(mesh)
+        if axes is None:
+            axes = default_mode_axes(mesh, batch_axis)
     # Batched-entry plan reuse: ``batch_bucket`` plans (and tunes) as if the
     # batch were the bucket size, so coalesced launches of varying batch
     # share one plan-cache entry — the serving layer's warmed buckets

@@ -234,7 +234,7 @@ def lower_coeff_grad(
     GSPMD something it can partition (and psum across shards); a
     ``pallas_call`` on multi-device operands has no SPMD rule.
     """
-    from .plan import _pow2_clamp
+    from .plan import _lane_tile
 
     a2d, _ = mode_unfold(a, mode)
     g2d, _ = mode_unfold(g, mode)
@@ -255,8 +255,8 @@ def lower_coeff_grad(
             dc = jnp.swapaxes(a2d, 0, 1) @ g2d
         else:
             dc = ops.sr_gemm(jnp.swapaxes(a2d, 0, 1), g2d,
-                             bm=_pow2_clamp(n), bn=_pow2_clamp(k),
-                             bk=_pow2_clamp(rows), use_pallas=use_pallas)
+                             bm=_lane_tile(n), bn=_lane_tile(k),
+                             bk=_lane_tile(rows), use_pallas=use_pallas)
     return dc, info
 
 

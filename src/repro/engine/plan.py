@@ -68,6 +68,7 @@ __all__ = [
     "order_costs",
     "macs_for_order",
     "sparsity_signature",
+    "lane_tile_ok",
     "fused_tile_sizes",
     "fused_vmem_bytes",
     "fused3_tile_sizes",
@@ -92,9 +93,12 @@ __all__ = [
 
 DEFAULT_ESOP_THRESHOLD = 0.3  # zero-block fraction at which block-ESOP wins
 MIN_KERNEL_DIM = 8  # below this, padding overhead beats the kernels
-# VMEM the fused kernel may claim for its tiles + scratch: roughly half a
-# TPU core's ~16 MB, leaving headroom for Pallas pipelining internals.
-DEFAULT_VMEM_BUDGET = 8 * 1024 * 1024
+# VMEM the fused kernels may claim, as the footprint model counts it (the
+# double-buffered blocks, scratch and the largest product temporary, in the
+# (8, 128)-tiled layout): three quarters of the 16 MiB scoped-VMEM limit
+# the TPU compiler enforces by default, the rest left for the internal
+# scratch the model does not count.
+DEFAULT_VMEM_BUDGET = 12 * 1024 * 1024
 # Per-shard stages below this many (batched) MACs run the einsum fallback:
 # at these sizes the kernel launch + unfold padding overhead beats any
 # streaming win (BENCH_distributed_engine D3_dense_32 measured the kernel
@@ -130,6 +134,50 @@ def _pow2_ceil_clamp(d: int, lo: int = 8, hi: int = 128) -> int:
 
 def _pad_up(d: int, b: int) -> int:
     return -(-d // b) * b
+
+
+# TPU tiling rule for a Pallas block (Mosaic refuses anything else): the
+# last dim is a multiple of 128 lanes or spans the whole array dim, the
+# second-to-last a multiple of 8 sublanes or the whole dim.  Every tile
+# here is a power of two >= 8, so the sublane half always holds.
+LANE = 128
+
+
+def lane_tile_ok(tile: int, extent: int) -> bool:
+    """True when ``tile`` may be a block's lane (last) dim over ``extent``.
+
+    Operands are zero-padded to a multiple of their tile, so a tile of at
+    least the extent is one block spanning the whole padded dim.
+    """
+    return tile % LANE == 0 or tile >= extent
+
+
+def _lane_tile(d: int, seed: int | None = None) -> int:
+    """Lane-dim tile for extent ``d``: ``seed`` (an ESOP-aligned grid) when
+    it obeys :func:`lane_tile_ok`, else the pow2 ceiling clamped to 128."""
+    t = min(seed or LANE, _pow2_ceil_clamp(d))
+    return t if lane_tile_ok(t, d) else _pow2_ceil_clamp(d)
+
+
+def _fit_vmem(tiles: dict[str, int], footprint, vmem_budget: int
+              ) -> dict[str, int] | None:
+    """Halve the largest of ``bu``/``bnb``/``bnc`` until ``footprint(tiles)``
+    fits ``vmem_budget``; None when even the floor tiles do not fit.
+
+    Only the leading and sublane tiles shrink: the lane tiles (``bna``,
+    ``bka``) are already the smallest lane-legal choice for their extent,
+    so VMEM pressure can only come off the rows and the nb/nc slabs.
+    ``bu`` is a leading block dim in every fused kernel, so it may go down
+    to 1; ``bnb``/``bnc`` are sublane dims and stop at 8.
+    """
+    floor = {"bu": 1, "bnb": 8, "bnc": 8}
+    while footprint(tiles) > vmem_budget:
+        shrinkable = [k for k in floor if tiles.get(k, 0) > floor[k]]
+        if not shrinkable:
+            return None
+        k = max(shrinkable, key=lambda k: tiles[k])
+        tiles[k] = 1 << ((tiles[k] - 1).bit_length() - 1)
+    return tiles
 
 
 @dataclasses.dataclass(frozen=True)
@@ -368,9 +416,11 @@ def _stage_blocks(rows: int, n: int, k: int,
                   block_sizes: tuple[int, int, int] | None) -> tuple[int, int, int]:
     if block_sizes is not None:
         return block_sizes
-    # Default: MXU-aligned 128, shrunk (power of two) for small operands so
-    # block-sparsity detection and padding stay proportionate.
-    return (_pow2_clamp(rows), _pow2_clamp(k), _pow2_clamp(n))
+    # Default: MXU-aligned 128, shrunk to the pow2 ceiling of small
+    # operands so block-sparsity detection and padding stay proportionate.
+    # All three are lane-legal (the backward dispatches swap tile roles, so
+    # bm becomes a lane dim there too).
+    return (_lane_tile(rows), _lane_tile(k), _lane_tile(n))
 
 
 def _plan_stage(
@@ -537,24 +587,36 @@ def order_costs(
     return out
 
 
+def _vmem(shape: tuple[int, ...], itemsize: int) -> int:
+    """Bytes of one VMEM buffer of ``shape`` in Mosaic's tiled layout: the
+    last two dims are padded to (8 rows × 32-bit packing, 128 lanes)."""
+    *lead, sub, lane = shape
+    rows = 8 * max(1, 4 // itemsize)
+    return math.prod(lead) * _pad_up(sub, rows) * _pad_up(lane, LANE) * itemsize
+
+
 def fused_vmem_bytes(bu: int, bka: int, bnb: int, bna: int, kbp: int,
                      itemsize: int, accum: str = "plain") -> int:
     """Modeled VMEM footprint of the fused kernel at these tile sizes.
 
-    Streamed operands are double-buffered by the Pallas pipeline (×2); the
-    stage-a partial and the output accumulator are fp32 scratch.
-    ``accum="compensated"`` adds the Neumaier comp register mirroring the
-    output accumulator (engine/numerics.py) — the footprint the budget
-    ladder sees, so forcing compensation can itself demote fusion depth.
+    Streamed operands and the output tile are double-buffered by the
+    Pallas pipeline (×2); the stage-a partial and the output accumulator
+    are fp32 scratch, and stage b's product is one more accumulator-sized
+    fp32 temporary.  Every buffer is counted in its (8, 128)-tiled layout,
+    which is what the TPU compiler allocates (a 64-wide last dim costs 128
+    lanes).  ``accum="compensated"`` adds the Neumaier comp register
+    mirroring the output accumulator (engine/numerics.py) — the footprint
+    the budget ladder sees, so forcing compensation can itself demote
+    fusion depth.
     """
-    comp = 4 * bu * bka * kbp if accum == "compensated" else 0
-    return (2 * bu * bnb * bna * itemsize   # streamed X slab
-            + 2 * bna * bka * itemsize      # streamed C_a block
-            + 2 * bnb * kbp * itemsize      # resident C_b slab
-            + 4 * bu * bnb * bka            # stage-a partial (f32)
-            + 4 * bu * bka * kbp            # output accumulator (f32)
-            + comp                          # Neumaier comp (f32, optional)
-            + 2 * bu * bka * kbp * itemsize)  # output tile
+    acc = _vmem((bu, bka, kbp), 4)
+    out_isz = itemsize if accum == "plain" else 4
+    return (2 * _vmem((bu, bnb, bna), itemsize)   # streamed X slab
+            + 2 * _vmem((bna, bka), itemsize)     # streamed C_a block
+            + 2 * _vmem((bnb, kbp), itemsize)     # resident C_b slab
+            + _vmem((bu, bnb, bka), 4)            # stage-a partial (f32)
+            + acc * (3 if accum == "compensated" else 2)  # acc, temp, comp
+            + 2 * _vmem((bu, bka, kbp), out_isz))  # output tile
 
 
 def fused_tile_sizes(
@@ -566,36 +628,26 @@ def fused_tile_sizes(
     """Pick ``(bu, bka, bnb, bna, kbp)`` fitting the VMEM budget, or None.
 
     ``start`` optionally seeds ``(bka, bna, bnb)`` (the planner aligns them
-    with the staged stages' ESOP block grids so sparse skipping composes).
+    with the staged stages' ESOP block grids so sparse skipping composes);
+    a lane seed that breaks :func:`lane_tile_ok` falls back to the default.
     Kb is not blocked (the accumulator holds the full padded slab width so
     stage b never revisits a partial), which is what bounds fusability:
-    when no power-of-two shrink of the other tiles fits, the pair must run
+    when no power-of-two shrink of ``bu``/``bnb`` fits, the pair must run
     staged.
     """
     kbp = kb_padded(kb)
     bka0, bna0, bnb0 = start if start is not None else (None, None, None)
-    tiles = {
+    tiles = _fit_vmem({
         "bu": _pow2_clamp(rows_total),
-        "bka": min(bka0 or 128, _pow2_ceil_clamp(ka)),
+        "bka": _lane_tile(ka, bka0),
         # bnb only sizes the on-chip partial (total traffic is bnb-
         # independent), so it starts small
         "bnb": min(bnb0 or 32, _pow2_ceil_clamp(nb, hi=32)),
-        "bna": min(bna0 or 128, _pow2_ceil_clamp(na)),
-    }
-
-    def footprint():
-        return fused_vmem_bytes(tiles["bu"], tiles["bka"], tiles["bnb"],
-                                tiles["bna"], kbp, itemsize, accum)
-
-    while footprint() > vmem_budget:
-        shrinkable = [k for k in ("bu", "bka", "bnb", "bna") if tiles[k] > 8]
-        if not shrinkable:
-            return None
-        k = max(shrinkable, key=lambda k: tiles[k])
-        # snap to the next power of two below (ESOP-aligned seeds may be
-        # non-pow2, e.g. 48 -> 32, never 24): keeps the autotune lattice
-        # and the TPU sublane/lane multiples intact, floor 8
-        tiles[k] = 1 << ((tiles[k] - 1).bit_length() - 1)
+        "bna": _lane_tile(na, bna0),
+    }, lambda t: fused_vmem_bytes(t["bu"], t["bka"], t["bnb"], t["bna"], kbp,
+                                  itemsize, accum), vmem_budget)
+    if tiles is None:
+        return None
     return tiles["bu"], tiles["bka"], tiles["bnb"], tiles["bna"], kbp
 
 
@@ -604,23 +656,24 @@ def fused3_vmem_bytes(bu: int, bka: int, bnb: int, bnc: int, bna: int,
                       accum: str = "plain") -> int:
     """Modeled VMEM footprint of the whole-transform megakernel.
 
-    Streamed operands are double-buffered by the Pallas pipeline (×2); the
-    two inter-stage partials and the output accumulator are fp32 scratch.
-    The ``bu·bka·Kbp·Kcp`` accumulator term dominates and is what bounds
+    Counted as :func:`fused_vmem_bytes` counts the pair: double-buffered
+    streamed operands and output tile, fp32 partials and accumulator, one
+    accumulator-sized stage-3 product, all in the (8, 128)-tiled layout.
+    The ``bu·bka·Kbp·Kcp`` accumulator terms dominate and are what bound
     triple fusability as the transform extents grow —
-    ``accum="compensated"`` doubles it (the Neumaier comp register), the
-    numerics lever that demotes triple → pair under a tight budget.
+    ``accum="compensated"`` adds one more (the Neumaier comp register),
+    the numerics lever that demotes triple → pair under a tight budget.
     """
-    comp = 4 * bu * bka * kbp * kcp if accum == "compensated" else 0
-    return (2 * bu * bnc * bnb * bna * itemsize  # streamed X slab
-            + 2 * bna * bka * itemsize           # streamed C_a block
-            + 2 * bnb * kbp * itemsize           # resident C_b slab
-            + 2 * bnc * kcp * itemsize           # resident C_c slab
-            + 4 * bu * bnc * bnb * bka           # stage-1 partial (f32)
-            + 4 * bu * bnc * bka * kbp           # stage-2 partial (f32)
-            + 4 * bu * bka * kbp * kcp           # output accumulator (f32)
-            + comp                               # Neumaier comp (optional)
-            + 2 * bu * bka * kbp * kcp * itemsize)  # output tile
+    acc = _vmem((bu, bka, kbp, kcp), 4)
+    out_isz = itemsize if accum == "plain" else 4
+    return (2 * _vmem((bu, bnc, bnb, bna), itemsize)  # streamed X slab
+            + 2 * _vmem((bna, bka), itemsize)         # streamed C_a block
+            + 2 * _vmem((bnb, kbp), itemsize)         # resident C_b slab
+            + 2 * _vmem((bnc, kcp), itemsize)         # resident C_c slab
+            + _vmem((bu, bnc, bnb, bka), 4)           # stage-1 partial
+            + _vmem((bu, bnc, bka, kbp), 4)           # stage-2 partial
+            + acc * (3 if accum == "compensated" else 2)  # acc, temp, comp
+            + 2 * _vmem((bu, bka, kbp, kcp), out_isz))  # output tile
 
 
 def fused3_tile_sizes(
@@ -636,34 +689,24 @@ def fused3_tile_sizes(
     them with the staged stages' ESOP block grids so sparse skipping
     composes).  Kb and Kc are not blocked (the partials/accumulator hold
     the full padded slab widths so stages 2–3 never revisit a partial);
-    shrinking ``bka`` is the pressure valve, at the cost of one extra X
-    re-stream per ka-block — the HBM model, not this function, judges
-    whether that trade still beats the pair kernel.
+    the lane tiles ``bka``/``bna`` are fixed by :func:`lane_tile_ok`, so
+    ``bu``/``bnb``/``bnc`` take the VMEM pressure, floor 8.
     """
     kbp, kcp = kb_padded(kb), kb_padded(kc)
     bka0, bna0, bnb0, bnc0 = start if start is not None else (None,) * 4
-    tiles = {
+    tiles = _fit_vmem({
         "bu": _pow2_clamp(rows_total),
-        "bka": min(bka0 or 128, _pow2_ceil_clamp(ka)),
+        "bka": _lane_tile(ka, bka0),
         # bnb/bnc only size the on-chip partials (total traffic is
         # independent of both), so they start small
         "bnb": min(bnb0 or 16, _pow2_ceil_clamp(nb, hi=16)),
         "bnc": min(bnc0 or 16, _pow2_ceil_clamp(nc, hi=16)),
-        "bna": min(bna0 or 128, _pow2_ceil_clamp(na)),
-    }
-
-    def footprint():
-        return fused3_vmem_bytes(tiles["bu"], tiles["bka"], tiles["bnb"],
-                                 tiles["bnc"], tiles["bna"], kbp, kcp,
-                                 itemsize, accum)
-
-    while footprint() > vmem_budget:
-        shrinkable = [k for k in ("bu", "bka", "bnb", "bnc", "bna")
-                      if tiles[k] > 8]
-        if not shrinkable:
-            return None
-        k = max(shrinkable, key=lambda k: tiles[k])
-        tiles[k] = 1 << ((tiles[k] - 1).bit_length() - 1)
+        "bna": _lane_tile(na, bna0),
+    }, lambda t: fused3_vmem_bytes(t["bu"], t["bka"], t["bnb"], t["bnc"],
+                                   t["bna"], kbp, kcp, itemsize, accum),
+        vmem_budget)
+    if tiles is None:
+        return None
     return (tiles["bu"], tiles["bka"], tiles["bnb"], tiles["bnc"],
             tiles["bna"], kbp, kcp)
 
@@ -897,11 +940,11 @@ def _plan_fusion3(
             accum=accum)
         if tiles is None:
             # no tiling keeps both partials on-chip: record the footprint
-            # at the floor tiles (8 everywhere) — the smallest this
-            # assignment could ever need vs what the budget allows
+            # at the floor tiles (bu = 1, bnb = bnc = 8, the lane tiles) —
+            # the smallest this assignment could ever need vs the budget
             vmem_floors.append(fused3_vmem_bytes(
-                8, 8, 8, 8, 8, kb_padded(kb), kb_padded(kc), itemsize,
-                accum))
+                1, _lane_tile(ka), 8, 8, _lane_tile(na), kb_padded(kb),
+                kb_padded(kc), itemsize, accum))
             continue
         bu, bka, bnb, bnc, bna, kbp, kcp = tiles
         mask_a = np.asarray(_padded_block_mask(ca, bna, bka))
@@ -1023,9 +1066,10 @@ def _plan_fusion(
             accum=accum)
         if tiles is None:
             # no tiling keeps the resident slab on-chip: record the floor
-            # footprint (8-everywhere tiles) vs the budget
+            # footprint (bu = 1, bnb = 8, the lane tiles) vs the budget
             vmem_floors.append(
-                fused_vmem_bytes(8, 8, 8, 8, kb_padded(kb), itemsize, accum))
+                fused_vmem_bytes(1, _lane_tile(ka), 8, _lane_tile(na),
+                                 kb_padded(kb), itemsize, accum))
             continue
         bu, bka, bnb, bna, kbp = tiles
         mask_a = np.asarray(_padded_block_mask(ca, bna, bka))
@@ -1128,7 +1172,7 @@ def chain_vmem_bytes(bu: int, bka: int, bnb: int, bna: int, kbp: int,
     window, nothing else — the partial it is copied from already exists.
     """
     return (fused_vmem_bytes(bu, bka, bnb, bna, kbp, itemsize, accum)
-            + 2 * bu * bnb * bka * itemsize)
+            + 2 * _vmem((bu, bnb, bka), itemsize))
 
 
 def chain_tile_sizes(
@@ -1138,30 +1182,22 @@ def chain_tile_sizes(
 ) -> tuple[int, int, int, int, int] | None:
     """Pick ``(bu, bka, bnb, bna, kbp)`` for the chain-pair kernel, or None.
 
-    Same shrink ladder as :func:`fused_tile_sizes` under the chain
+    Same ladder as :func:`fused_tile_sizes` under the chain
     footprint (:func:`chain_vmem_bytes`).  No ESOP seeds: the chain's b
     stream is dense by construction (every emitted ``y1`` block must be
     written), so only the a-side compaction applies and the default
     lattice is the right one.
     """
     kbp = kb_padded(kb)
-    tiles = {
+    tiles = _fit_vmem({
         "bu": _pow2_clamp(rows_total),
-        "bka": _pow2_ceil_clamp(ka),
+        "bka": _lane_tile(ka),
         "bnb": _pow2_ceil_clamp(nb, hi=32),
-        "bna": _pow2_ceil_clamp(na),
-    }
-
-    def footprint():
-        return chain_vmem_bytes(tiles["bu"], tiles["bka"], tiles["bnb"],
-                                tiles["bna"], kbp, itemsize, accum)
-
-    while footprint() > vmem_budget:
-        shrinkable = [k for k in ("bu", "bka", "bnb", "bna") if tiles[k] > 8]
-        if not shrinkable:
-            return None
-        k = max(shrinkable, key=lambda k: tiles[k])
-        tiles[k] = 1 << ((tiles[k] - 1).bit_length() - 1)
+        "bna": _lane_tile(na),
+    }, lambda t: chain_vmem_bytes(t["bu"], t["bka"], t["bnb"], t["bna"], kbp,
+                                  itemsize, accum), vmem_budget)
+    if tiles is None:
+        return None
     return tiles["bu"], tiles["bka"], tiles["bnb"], tiles["bna"], kbp
 
 
@@ -1177,8 +1213,8 @@ def chain3_vmem_bytes(bu: int, bka: int, bnb: int, bnc: int, bna: int,
     """
     return (fused3_vmem_bytes(bu, bka, bnb, bnc, bna, kbp, kcp, itemsize,
                               accum)
-            + 2 * bu * bnc * bnb * bka * itemsize
-            + 2 * bu * bnc * bka * kbp * itemsize)
+            + 2 * _vmem((bu, bnc, bnb, bka), itemsize)
+            + 2 * _vmem((bu, bnc, bka, kbp), itemsize))
 
 
 def chain3_tile_sizes(
@@ -1190,26 +1226,17 @@ def chain3_tile_sizes(
     or None — the :func:`fused3_tile_sizes` ladder under the chain
     footprint (:func:`chain3_vmem_bytes`)."""
     kbp, kcp = kb_padded(kb), kb_padded(kc)
-    tiles = {
+    tiles = _fit_vmem({
         "bu": _pow2_clamp(rows_total),
-        "bka": _pow2_ceil_clamp(ka),
+        "bka": _lane_tile(ka),
         "bnb": _pow2_ceil_clamp(nb, hi=16),
         "bnc": _pow2_ceil_clamp(nc, hi=16),
-        "bna": _pow2_ceil_clamp(na),
-    }
-
-    def footprint():
-        return chain3_vmem_bytes(tiles["bu"], tiles["bka"], tiles["bnb"],
-                                 tiles["bnc"], tiles["bna"], kbp, kcp,
-                                 itemsize, accum)
-
-    while footprint() > vmem_budget:
-        shrinkable = [k for k in ("bu", "bka", "bnb", "bnc", "bna")
-                      if tiles[k] > 8]
-        if not shrinkable:
-            return None
-        k = max(shrinkable, key=lambda k: tiles[k])
-        tiles[k] = 1 << ((tiles[k] - 1).bit_length() - 1)
+        "bna": _lane_tile(na),
+    }, lambda t: chain3_vmem_bytes(t["bu"], t["bka"], t["bnb"], t["bnc"],
+                                   t["bna"], kbp, kcp, itemsize, accum),
+        vmem_budget)
+    if tiles is None:
+        return None
     return (tiles["bu"], tiles["bka"], tiles["bnb"], tiles["bnc"],
             tiles["bna"], kbp, kcp)
 
@@ -1364,8 +1391,8 @@ def plan_adjoint_chain(
                 "kind": "adjoint_fusion_degradation", "from": "triple",
                 "reason": "vmem_budget",
                 "vmem_bytes_min": chain3_vmem_bytes(
-                    8, 8, 8, 8, 8, kb_padded(a1.k), kb_padded(a2.k),
-                    itemsize, accum),
+                    1, _lane_tile(a0.k), 8, 8, _lane_tile(a0.n),
+                    kb_padded(a1.k), kb_padded(a2.k), itemsize, accum),
                 "vmem_budget": vmem_budget,
             })
         else:
@@ -1401,7 +1428,8 @@ def plan_adjoint_chain(
                 "kind": "adjoint_fusion_degradation", "from": "pair",
                 "reason": "vmem_budget",
                 "vmem_bytes_min": chain_vmem_bytes(
-                    8, 8, 8, 8, kb_padded(a1.k), itemsize, accum),
+                    1, _lane_tile(a0.k), 8, _lane_tile(a0.n),
+                    kb_padded(a1.k), itemsize, accum),
                 "vmem_budget": vmem_budget,
             })
             return declined(events)

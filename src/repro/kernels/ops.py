@@ -663,6 +663,8 @@ def coeff_grad_batch(as_list, gs_list, br: int = 128,
     rmax = max(a.shape[0] for a in as_list)
     nmax = max(a.shape[1] for a in as_list)
     kmax = max(g.shape[1] for g in gs_list)
+    # TPU tiling rule: br is the blocks' sublane dim (kb_padded gives a
+    # multiple of 8); the lane dims N/K are whole padded extents.
     br_eff = min(br, kb_padded(rmax))
     rp = -(-rmax // br_eff) * br_eff
     np_, kp = kb_padded(nmax), kb_padded(kmax)

@@ -19,7 +19,7 @@ import traceback
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCH_IDS, LONG_CONTEXT_OK, SHAPES, input_specs, load_config
 from repro.launch.mesh import (TP, act_rules, batch_specs, dp_axes,
@@ -37,7 +37,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     n = int(np.prod(shape))
     if len(jax.devices()) == n:
-        return jax.make_mesh(shape, axes)
+        return jax.make_mesh(shape, axes,
+                             axis_types=(AxisType.Auto,) * len(axes))
     # 512 placeholder devices back both meshes: single-pod = first 256.
     devs = np.asarray(jax.devices()[:n]).reshape(shape)
     return Mesh(devs, axes)

@@ -8,13 +8,24 @@ from __future__ import annotations
 from typing import Any
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+
+
+def auto_axes(mesh):
+    """``mesh`` with every axis in Auto mode.
+
+    ``jax.make_mesh`` makes Explicit axes by default, and Explicit axes
+    reject ``with_sharding_constraint`` and ambiguous gathers.  This
+    repository pins layouts with constraints and lets the partitioner
+    propagate the rest, so every mesh it shards over is Auto.
+    """
+    return mesh.update(axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def dp_axes(multi_pod: bool):
@@ -84,6 +95,7 @@ def specs_from_axes(axes_tree, rules: dict):
 
 
 def shardings_from_axes(mesh, axes_tree, rules: dict):
+    mesh = auto_axes(mesh)
     return jax.tree.map(
         lambda ax: NamedSharding(mesh, spec_of(ax, rules)), axes_tree,
         is_leaf=lambda x: isinstance(x, tuple))

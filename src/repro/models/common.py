@@ -46,8 +46,10 @@ class ShardCtx:
         if self.mesh is None or self.rules is None:
             return x
         from jax.sharding import NamedSharding
+
+        from ..launch.mesh import auto_axes
         return jax.lax.with_sharding_constraint(
-            x, NamedSharding(self.mesh, self.spec(*logical)))
+            x, NamedSharding(auto_axes(self.mesh), self.spec(*logical)))
 
 
 def row_parallel_matmul(a: jnp.ndarray, w: jnp.ndarray, ctx: "ShardCtx",
@@ -64,7 +66,7 @@ def row_parallel_matmul(a: jnp.ndarray, w: jnp.ndarray, ctx: "ShardCtx",
     axis = ctx.rules.get(in_rule) if ctx.rules else None
     if ctx.mesh is None or axis is None or not ctx.rules.get("rowp"):
         return a @ w
-    from ..compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     b_ax = ctx.rules.get("batch")
 

@@ -16,8 +16,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from ..compat import shard_map
 
 from .common import ShardCtx, apply_norm, dense_init, init_norm, norm_axes
 

@@ -29,6 +29,7 @@ def virtual_devices():
 
     def run(code: str, devices: int = 8) -> str:
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"  # virtual devices; never the chip
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
         env["PYTHONPATH"] = _SRC + os.pathsep + env.get("PYTHONPATH", "")
         r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],

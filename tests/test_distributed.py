@@ -116,7 +116,7 @@ class TestDistributedGemt:
     def test_compressed_psum_multi_device(self, virtual_devices):
         virtual_devices("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.runtime import compressed_psum
         mesh = jax.make_mesh((4,), ("x",))

@@ -28,9 +28,11 @@ def _problem(dims, ranks, seed=0, dtype=np.float32):
 
 
 def _block_sparse(n, k, keep, block):
-    """Coefficient matrix with the given boolean block-keep pattern."""
+    """Coefficient matrix with the given boolean block-keep pattern
+    (``block`` is an edge or a (rows, cols) pair)."""
     dense = RNG.normal(size=(n, k)).astype(np.float32)
-    return jnp.asarray(np.kron(keep, np.ones((block, block))) * dense)
+    shape = block if isinstance(block, tuple) else (block, block)
+    return jnp.asarray(np.kron(keep, np.ones(shape)) * dense)
 
 
 class TestFusedOp:
@@ -229,8 +231,10 @@ class TestFusionDecision:
         need = plan.fused.vmem_bytes
         assert build_plan(shape, jnp.float32, *cs, fuse="pair",
                           vmem_budget=need).fused is not None
-        # the minimal-footprint tiling (all dims at 8) is the true floor
-        floor = fused_vmem_bytes(8, 8, 8, 8, plan.fused.kbp, 4)
+        # the minimal-footprint tiling (bu = 1, bnb = 8, the fixed lane
+        # tiles) is the true floor
+        f = plan.fused
+        floor = fused_vmem_bytes(1, f.bka, 8, f.bna, f.kbp, 4)
         assert build_plan(shape, jnp.float32, *cs, fuse="pair",
                           vmem_budget=floor - 1).fused is None
 
@@ -262,11 +266,13 @@ class TestFusionDecision:
         skipping — so this pins the compressive case where ESOP-on-a is
         the clear bytes winner.)
         """
-        keep = np.array([[1], [0], [0], [1]]).astype(bool)  # 50% zero blocks
-        c3 = _block_sparse(256, 64, keep, 64)
+        # 50% zero blocks at a TPU-legal grid: the na tile is X's lane dim,
+        # so it is 128 here (a multiple of 128 or the whole extent)
+        keep = np.array([[1], [0]]).astype(bool)
+        c3 = _block_sparse(256, 64, keep, (128, 64))
         c1, c2 = jnp.asarray(np.eye(64, dtype=np.float32)), _rand(48, 48)
         plan = build_plan((64, 48, 256), jnp.float32, c1, c2, c3, fuse="pair",
-                          block_sizes=(128, 64, 64))
+                          block_sizes=(128, 64, 128))
         assert plan.fused is not None
         assert plan.fused.mode_a == 3
         assert plan.fused.zero_block_frac_a == pytest.approx(0.5)

@@ -109,6 +109,18 @@ class TestGradMatchesReference:
         got, want = _vjp_pair(x, cs, g, fuse=False)
         assert_grads_close(got, want)
 
+    @pytest.mark.parametrize("fuse", ["pair", None])
+    def test_fused_walk_with_staged_esop_stage(self, fuse):
+        """The fused backward walk runs as one jit; an ESOP stage staged
+        inside it (here the adjoint tail over the block-sparse C1ᵀ) must
+        take a schedule built from the concrete matrix, not its tracer."""
+        x = _rand(2, 16, 24, 8)
+        keep = np.kron(np.eye(2), np.ones((8, 8)))  # 50% zero 8x8 blocks
+        cs = (_rand(16, 16) * keep, _rand(24, 24), _rand(8, 8))
+        g = _rand(2, 16, 24, 8)
+        got, want = _vjp_pair(x, cs, g, fuse=fuse, block_sizes=(8, 8, 8))
+        assert_grads_close(got, want)
+
     def test_sparse_esop_coefficients(self):
         """Block-sparse C engages ESOP forward *and* in the adjoint chain
         (transposed structure), with identical gradients."""

@@ -98,7 +98,7 @@ class TestCompression:
 
     def test_compressed_psum_single_axis(self):
         """shard_map over the (single-device) mesh: psum semantics hold."""
-        from repro.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.runtime import compressed_psum
         mesh = jax.make_mesh((1,), ("x",))
