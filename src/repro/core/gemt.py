@@ -197,7 +197,13 @@ def dxt3d(
     with sp:
         build = inverse_coefficient_matrix if inverse else coefficient_matrix
         n1, n2, n3 = x.shape[-3:] if engine else x.shape
-        c1, c2, c3 = build(kind, n1), build(kind, n2), build(kind, n3)
+        sp_c = _trace.NULL_SPAN
+        if _trace.enabled():
+            sp_c = _trace.span("dxt3d.coefficients",
+                               {"kind": kind, "inverse": bool(inverse),
+                                "sizes": (n1, n2, n3)})
+        with sp_c:
+            c1, c2, c3 = build(kind, n1), build(kind, n2), build(kind, n3)
         if jnp.iscomplexobj(c1) and not jnp.iscomplexobj(x):
             x = x.astype(c1.dtype)
         if engine:

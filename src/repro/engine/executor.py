@@ -121,10 +121,16 @@ def _fingerprint(c: jnp.ndarray) -> str:
         return f"traced:{tuple(c.shape)}:{jnp.dtype(c.dtype).name}"
 
     def compute():
-        cn = np.asarray(c)
-        h = hashlib.sha1(f"{cn.shape}|{cn.dtype}".encode())
-        h.update(np.packbits(cn != 0).tobytes())
-        return h.hexdigest()[:16]
+        sp = _trace.NULL_SPAN
+        if _trace.enabled():
+            sp = _trace.span("plan.fingerprint",
+                             {"shape": tuple(c.shape),
+                              "dtype": jnp.dtype(c.dtype).name})
+        with sp:
+            cn = np.asarray(c)
+            h = hashlib.sha1(f"{cn.shape}|{cn.dtype}".encode())
+            h.update(np.packbits(cn != 0).tobytes())
+            return h.hexdigest()[:16]
 
     return _FP_MEMO.get_or_compute(c, "fp", compute)
 

@@ -39,11 +39,11 @@ from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       get_registry, inc, observe, set_gauge, set_registry)
 from .trace import (NULL_SPAN, Span, Tracer, clear, disable, enable,
                     enabled, get_fault_hook, get_tracer, set_fault_hook,
-                    set_tracer, span, spans, traced)
+                    set_tracer, span, spans)
 
 __all__ = [
     # spans
-    "Span", "Tracer", "NULL_SPAN", "span", "traced", "enable", "disable",
+    "Span", "Tracer", "NULL_SPAN", "span", "enable", "disable",
     "enabled", "spans", "clear", "get_tracer", "set_tracer",
     "set_fault_hook", "get_fault_hook",
     # metrics

@@ -3,7 +3,7 @@
 Chrome-trace format (the subset emitted here): a JSON object with a
 ``traceEvents`` list of *complete* events — ``ph: "X"`` with ``ts``/``dur``
 in microseconds — one per recorded span, ``args`` carrying the span's
-structured attributes plus ``span_id``/``parent_id``.  Load the file in
+structured attributes plus ``span_id``/``parent_id``/``root_id``.  Load the file in
 ``chrome://tracing`` or https://ui.perfetto.dev.  A ``counters`` key (not
 part of the Chrome schema; both viewers ignore unknown keys) embeds the
 metrics-registry snapshot taken at export time.
@@ -60,6 +60,7 @@ def chrome_trace(spans=None, registry=None) -> dict:
         args = {k: _jsonable(v) for k, v in s.attrs.items()}
         args["span_id"] = s.span_id
         args["parent_id"] = s.parent_id
+        args["root_id"] = s.root_id
         events.append({
             "name": s.name,
             "cat": str(s.attrs.get("cat", "engine")),

@@ -15,10 +15,19 @@ compile — not device execution (jax dispatch is asynchronous).  Inside a
 compilation; the span *structure* (which stages lower, in what nesting)
 is exact either way.
 
+Profiler bridge: a span also records while a JAX profiler session is
+recording (``jax.profiler.start_trace`` … ``stop_trace``), tracer enabled
+or not.  It then enters a ``jax.profiler.TraceAnnotation`` of the same
+name, with its attributes as event stats, so the engine's spans
+appear on the profile's host plane on the device trace's clock.  Each span
+carries ``root_id``, the id of the outermost span open on its thread when
+it started (its own id for a root): every span of one call shares it.
+
 Disabled-mode cost is the contract: :func:`span` returns the preallocated
 :data:`NULL_SPAN` singleton without allocating, and hot call sites guard
 attribute construction behind :func:`enabled`, so an untraced serve hot
-path pays one global load + attribute check per site.
+path pays one global load + attribute check and the profiler's
+is-recording check per site.
 
 Fault hook: the chaos layer (:mod:`repro.runtime.faults`) registers a
 callable via :func:`set_fault_hook` that receives every span *name* at the
@@ -31,11 +40,12 @@ installed the hot path is unchanged — one extra global load.
 """
 from __future__ import annotations
 
-import functools
 import itertools
 import threading
 import time
 from collections import deque
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Span",
@@ -49,13 +59,16 @@ __all__ = [
     "span",
     "spans",
     "clear",
-    "traced",
     "set_fault_hook",
     "get_fault_hook",
     "DEFAULT_CAPACITY",
 ]
 
 DEFAULT_CAPACITY = 8192
+
+# True only while a JAX profiler session records.  Bound once: every
+# guarded span site calls it.
+_profiling = TraceAnnotation.is_enabled
 
 
 class _NullSpan:
@@ -90,10 +103,11 @@ NULL_SPAN = _NullSpan()
 class Span:
     """One timed region.  Use as a context manager; ``set(**attrs)`` adds
     attributes (before, during or right after the region — the record is
-    buffered at ``__exit__``)."""
+    buffered at ``__exit__``; the profiler's copy takes those set before
+    ``__enter__``)."""
 
-    __slots__ = ("name", "attrs", "span_id", "parent_id", "depth",
-                 "t0_ns", "dur_ns", "_tracer")
+    __slots__ = ("name", "attrs", "span_id", "parent_id", "root_id", "depth",
+                 "t0_ns", "dur_ns", "_tracer", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict | None):
         self._tracer = tracer
@@ -101,9 +115,11 @@ class Span:
         self.attrs = dict(attrs) if attrs else {}
         self.span_id = next(tracer._ids)
         self.parent_id = 0
+        self.root_id = self.span_id
         self.depth = 0
         self.t0_ns = 0
         self.dur_ns = 0
+        self._annotation = None
 
     def set(self, **attrs):
         self.attrs.update(attrs)
@@ -113,13 +129,19 @@ class Span:
         stack = self._tracer._stack()
         if stack:
             self.parent_id = stack[-1].span_id
+            self.root_id = stack[0].span_id
             self.depth = len(stack)
         stack.append(self)
+        if _profiling():
+            self._annotation = TraceAnnotation(self.name, **self.attrs)
+            self._annotation.__enter__()
         self.t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.dur_ns = time.perf_counter_ns() - self.t0_ns
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -199,17 +221,19 @@ def set_tracer(tracer: Tracer) -> Tracer:
 
 def enabled() -> bool:
     """Cheap guard for hot call sites: build span names/attrs only when
-    this returns True, else use :data:`NULL_SPAN` directly.  True whenever
-    a fault hook is installed, so chaos drills reach :func:`span` (and the
+    this returns True, else use :data:`NULL_SPAN` directly.  True while
+    the tracer is enabled or a profiler session records, and whenever a
+    fault hook is installed, so chaos drills reach :func:`span` (and the
     hook) even with tracing off."""
-    return _TRACER.enabled or _FAULT_HOOK is not None
+    return _TRACER.enabled or _FAULT_HOOK is not None or _profiling()
 
 
 def span(name: str, attrs: dict | None = None):
-    """Start a span on the current tracer; :data:`NULL_SPAN` when disabled.
+    """Start a span on the current tracer; :data:`NULL_SPAN` unless the
+    tracer is enabled or a profiler session records.
 
-    ``attrs`` may be a zero-arg callable, evaluated only when tracing is
-    enabled (lazy construction for attribute dicts that cost something).
+    ``attrs`` may be a zero-arg callable, evaluated only when the span
+    records (lazy construction for attribute dicts that cost something).
     An installed fault hook fires first — it may raise or delay, standing
     in for the kernel/collective failure the span would have timed.
     """
@@ -217,7 +241,7 @@ def span(name: str, attrs: dict | None = None):
     if hook is not None:
         hook(name)
     t = _TRACER
-    if not t.enabled:
+    if not (t.enabled or _profiling()):
         return NULL_SPAN
     if callable(attrs):
         attrs = attrs()
@@ -241,26 +265,3 @@ def spans() -> list[Span]:
 
 def clear() -> None:
     _TRACER.clear()
-
-
-def traced(name: str | None = None, **static_attrs):
-    """Decorator form: ``@traced("plan")`` wraps calls in a span.  When
-    tracing is disabled the wrapper adds one attribute check per call."""
-
-    def deco(fn):
-        label = name or fn.__qualname__
-
-        @functools.wraps(fn)
-        def wrapper(*args, **kwargs):
-            hook = _FAULT_HOOK
-            if hook is not None:
-                hook(label)
-            t = _TRACER
-            if not t.enabled:
-                return fn(*args, **kwargs)
-            with Span(t, label, static_attrs):
-                return fn(*args, **kwargs)
-
-        return wrapper
-
-    return deco

@@ -62,21 +62,6 @@ def test_span_set_adds_attributes():
         assert s.tracer.spans()[0].attrs["extra"] == 42
 
 
-def test_traced_decorator():
-    @obs.traced("decorated", kind="test")
-    def f(v):
-        return v + 1
-
-    with obs.session() as s:
-        assert f(1) == 2
-        (sp,) = s.tracer.spans()
-    assert sp.name == "decorated" and sp.attrs == {"kind": "test"}
-    # disabled: plain call, nothing recorded
-    with obs.session(enable_tracing=False) as s:
-        assert f(1) == 2
-        assert s.tracer.spans() == []
-
-
 def test_ring_buffer_bounds_spans():
     with obs.session(capacity=4) as s:
         for i in range(10):
@@ -112,6 +97,155 @@ def test_untraced_engine_run_records_no_spans():
         assert s.tracer.spans() == []
         # metrics are always on, even with tracing off
         assert s.registry.value("engine.executions") == 1
+
+
+def test_root_id_shared_by_a_call_and_kept_past_a_dropped_parent():
+    with obs.session(capacity=2) as s:
+        with obs.span("outer") as outer:
+            with obs.span("mid") as mid:
+                with obs.span("leaf") as leaf:
+                    pass
+            with obs.span("sibling") as sib:
+                pass
+            in_flight = s.tracer.spans()
+        with obs.span("next") as nxt:
+            pass
+    assert outer.root_id == outer.span_id and nxt.root_id == nxt.span_id
+    assert mid.root_id == leaf.root_id == sib.root_id == outer.span_id
+    assert leaf.parent_id == mid.span_id and sib.parent_id == outer.span_id
+    # the buffer holds neither the open root nor the dropped leaf; the
+    # spans it holds still name their call
+    assert [sp.name for sp in in_flight] == ["mid", "sibling"]
+    assert {sp.root_id for sp in in_flight} == {outer.span_id}
+    doc = obs.chrome_trace([leaf, mid, sib, outer])
+    assert {e["args"]["root_id"] for e in doc["traceEvents"]} == {
+        outer.span_id}
+
+
+def _host_events(log_dir, names):
+    """``{name: [(start_ns, end_ns, stats), ...]}`` of the host plane's
+    events named in ``names``, in start order."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in names:
+                    out.setdefault(e.name, []).append(
+                        (e.start_ns, e.end_ns, dict(e.stats)))
+    return {k: sorted(v, key=lambda ev: ev[0]) for k, v in out.items()}
+
+
+def test_profiler_session_records_engine_spans_on_its_clock(tmp_path):
+    """With obs's tracer off, a recording profiler session switches the
+    spans on: they land in obs's buffer and, under the same names and
+    nesting, on the profile's host plane with their attributes."""
+    from repro.core import dxt3d
+
+    x = _rand(16, 16, 16)
+    clear_plan_cache()
+    with obs.session(enable_tracing=False) as s:
+        assert not obs.enabled()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            assert obs.enabled()
+            jax.block_until_ready(dxt3d(x, "dht", inverse=True, engine=True))
+        finally:
+            jax.profiler.stop_trace()
+        assert not obs.enabled()
+        assert obs.span("after") is obs.NULL_SPAN
+        spans = sorted(s.tracer.spans(), key=lambda sp: sp.t0_ns)
+    names = [sp.name for sp in spans]
+    for want in ("dxt3d:dht", "dxt3d.coefficients", "plan.fingerprint",
+                 "execute"):
+        assert want in names
+    assert names.count("plan.fingerprint") == 3
+    (root,) = [sp for sp in spans if sp.parent_id == 0]
+    assert root.name == "dxt3d:dht"
+    assert {sp.root_id for sp in spans} == {root.span_id}
+
+    events = _host_events(str(tmp_path), set(names))
+    assert {k: len(v) for k, v in events.items()} == {
+        n: names.count(n) for n in set(names)}
+    seen: dict = {}
+    placed = {}
+    for sp in spans:  # the k-th span of a name is its k-th event
+        k = seen[sp.name] = seen.get(sp.name, -1) + 1
+        placed[sp.span_id] = events[sp.name][k]
+    for sp in spans:
+        start, end, stats = placed[sp.span_id]
+        assert end - start >= sp.dur_ns * 0.999  # encloses obs's interval
+        if sp.parent_id:
+            p_start, p_end, _ = placed[sp.parent_id]
+            assert p_start <= start and end <= p_end, sp.name
+        for key, val in sp.attrs.items():
+            if isinstance(val, (bool, int, float, str)):
+                assert stats[key] == val, (sp.name, key)
+            elif isinstance(val, tuple):
+                assert stats[key] == str(val), (sp.name, key)
+    coeff = next(sp for sp in spans if sp.name == "dxt3d.coefficients")
+    assert coeff.attrs == {"kind": "dht", "inverse": True,
+                           "sizes": (16, 16, 16)}
+    assert placed[coeff.span_id][2]["sizes"] == "(16, 16, 16)"
+
+
+@pytest.mark.parametrize("tracer", [False, True])
+@pytest.mark.parametrize("hook", [False, True])
+@pytest.mark.parametrize("profiling", [False, True])
+def test_enabled_and_span_follow_one_rule(monkeypatch, tracer, hook,
+                                          profiling):
+    """``enabled()`` is tracer OR fault hook OR profiler recording; a span
+    records when the tracer is enabled or the profiler records; the hook
+    fires whenever one is installed."""
+    monkeypatch.setattr(trace_mod, "_profiling", lambda: profiling)
+    fired = []
+    prev = obs.set_fault_hook(fired.append if hook else None)
+    try:
+        with obs.session(enable_tracing=tracer) as s:
+            assert obs.enabled() == (tracer or hook or profiling)
+            sp = obs.span("x", {"a": 1})
+            with sp:
+                pass
+            recorded = s.tracer.spans()
+    finally:
+        obs.set_fault_hook(prev)
+    assert fired == (["x"] if hook else [])
+    if tracer or profiling:
+        assert [r.name for r in recorded] == ["x"]
+    else:
+        assert sp is obs.NULL_SPAN and recorded == []
+
+
+def test_fingerprint_span_only_on_memo_miss():
+    """Fingerprints are memoized on array identity: a second call with the
+    same matrices records no ``plan.fingerprint``; ``dxt3d`` builds new
+    matrices each call and so misses on all three every time."""
+    from repro.core import dxt3d
+
+    x, c1, c2, c3 = _problem()
+    with obs.session() as s:
+        gemt3_planned(x, c1, c2, c3)
+        first = [sp for sp in s.tracer.spans()
+                 if sp.name == "plan.fingerprint"]
+        s.tracer.clear()
+        gemt3_planned(x, c1, c2, c3)
+        assert [sp.name for sp in s.tracer.spans()
+                if sp.name == "plan.fingerprint"] == []
+        for _ in range(2):
+            s.tracer.clear()
+            dxt3d(x, "dht", inverse=True, engine=True)
+            fps = [sp for sp in s.tracer.spans()
+                   if sp.name == "plan.fingerprint"]
+            assert len(fps) == 3
+    assert len(first) == 3
+    assert first[0].attrs == {"shape": (16, 16), "dtype": "float32"}
 
 
 # ---------------------------------------------------------------------------
