@@ -1,17 +1,11 @@
 """Host time building the transform's three coefficient matrices per
 iteration: the program's ``dxt3d.coefficients`` spans over the traced
-cycle.  Nothing unless the program records one root ``dxt3d:{kind}`` span
-per traced iteration."""
+cycle."""
+import os
+import runpy
 
 
 def read(ctx):
-    from repro import obs
-
-    spans = obs.spans()
-    n = ctx.traced["iterations"] if ctx.traced else 0
-    roots = [s for s in spans
-             if s.parent_id == 0 and s.name.startswith("dxt3d:")]
-    if not n or len(roots) != n:
-        return None
-    return sum(s.dur_ns for s in spans
-               if s.name == "dxt3d.coefficients") / n / 1e6
+    helper = runpy.run_path(os.path.join(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))), "program_spans.py"))
+    return helper["per_iteration_ms"](ctx, "dxt3d.coefficients")

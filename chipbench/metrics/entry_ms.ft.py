@@ -1,17 +1,11 @@
 """Host time inside the engine's entry point per iteration: the program's
-root ``dxt3d:{kind}`` spans over the traced cycle, whose profiler session
-switches the spans on.  The inside counterpart of ``host_call_ms.ft``.
-Nothing unless the program records one root span per traced iteration
-(a program without the profiler bridge records none)."""
+root ``dxt3d:{kind}`` spans over the traced cycle.  The inside counterpart
+of ``host_call_ms.ft``."""
+import os
+import runpy
 
 
 def read(ctx):
-    from repro import obs
-
-    spans = obs.spans()
-    n = ctx.traced["iterations"] if ctx.traced else 0
-    roots = [s for s in spans
-             if s.parent_id == 0 and s.name.startswith("dxt3d:")]
-    if not n or len(roots) != n:
-        return None
-    return sum(s.dur_ns for s in roots) / n / 1e6
+    helper = runpy.run_path(os.path.join(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))), "program_spans.py"))
+    return helper["per_iteration_ms"](ctx, None)

@@ -909,13 +909,16 @@ def _execute_vjp(plan: GemtPlan, adj: GemtPlan, chain: AdjointChainPlan, x,
     walk: the fused programs are built host-side around precomputed ESOP
     schedules, which a traced coefficient cannot provide.
 
-    With tracing disabled the pieces run as ONE composed jit
-    (:func:`_execute_vjp_composed`) — same launches, one dispatch; the
-    multi-dispatch walk below exists so spans time real launches.
+    Unless obs's tracer is enabled or a fault hook is installed, the
+    pieces run as ONE composed jit (:func:`_execute_vjp_composed`) — same
+    launches, one dispatch; the multi-dispatch walk below exists so spans
+    time real launches.  A profiler session alone keeps the composed jit,
+    so a profile shows the program that runs without it.
     """
     if chain.depth < 2 or _is_traced(x, g, *cs.values(), *cts.values()):
         return _execute_vjp_staged(plan, adj, x, cs, cts, g, use_pallas)
-    if not _trace.enabled():
+    if not (_trace.get_tracer().enabled
+            or _trace.get_fault_hook() is not None):
         # hot path: the whole walk as one dispatch (identical launches)
         return _execute_vjp_composed(plan, adj, chain, x, cs, cts, g,
                                      use_pallas)

@@ -22,6 +22,11 @@ name, with its attributes as event stats, so the engine's spans
 appear on the profile's host plane on the device trace's clock.  Each span
 carries ``root_id``, the id of the outermost span open on its thread when
 it started (its own id for a root): every span of one call shares it.
+The check binds ``jax.profiler`` at import, so importing ``repro.obs``
+loads jax, as the rest of the package does.  A profiler session records
+spans and changes no code path: engine code that runs launches one by
+one so that spans time them asks the tracer (``get_tracer().enabled``),
+not :func:`enabled`.
 
 Disabled-mode cost is the contract: :func:`span` returns the preallocated
 :data:`NULL_SPAN` singleton without allocating, and hot call sites guard

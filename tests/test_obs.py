@@ -501,6 +501,41 @@ def test_fused_backward_spans_attributed_like_forward():
         assert sp.parent_id == vjp.span_id
 
 
+def test_profiler_session_keeps_the_composed_backward(tmp_path):
+    """A recording profiler session changes no code path: the custom-VJP
+    backward is the same one composed dispatch, with the same launch
+    counts, as with tracing off; it records ``vjp.backward`` and no
+    per-launch ``grad.*`` span."""
+    from repro.engine import executor
+
+    x, c1, c2, c3 = _problem(16)
+    loss = lambda *a: jnp.sum(jnp.abs(
+        gemt3_planned(*a, differentiable=True)))
+
+    def backward(profile):
+        clear_plan_cache()
+        with obs.session(enable_tracing=False) as s:
+            if profile:
+                jax.profiler.start_trace(str(tmp_path))
+            try:
+                jax.block_until_ready(
+                    jax.grad(loss, argnums=(0, 1, 2, 3))(x, c1, c2, c3))
+            finally:
+                if profile:
+                    jax.profiler.stop_trace()
+            fns = {k[0] for k in executor._SHARDED_FN_CACHE
+                   if isinstance(k, tuple)}
+            return grad_stats(), fns, [sp.name for sp in s.tracer.spans()]
+
+    stats, fns, names = backward(profile=False)
+    p_stats, p_fns, p_names = backward(profile=True)
+    assert p_stats == stats and stats["backward_calls"] == 1
+    assert p_fns == fns and "vjp_fused_walk" in fns
+    assert names == []
+    assert "vjp.backward" in p_names
+    assert not [n for n in p_names if n.startswith("grad.")]
+
+
 # ---------------------------------------------------------------------------
 # fusion-degradation events
 # ---------------------------------------------------------------------------
