@@ -7,8 +7,8 @@ distributed TriADA schedule (§4–§5, Eq. 7).  The paper-section→module map
 lives in ``docs/architecture.md``; the distributed recipes in
 ``docs/distributed.md``.
 """
-from .gemt import (PAREN_ORDERS, dxt3d, gemt3, gemt3_outer, gemt3_planned,
-                   macs, mode_product, time_steps)
+from .gemt import (PAREN_ORDERS, clear_coefficient_cache, dxt3d, gemt3,
+                   gemt3_outer, gemt3_planned, macs, mode_product, time_steps)
 from .transforms import (TRANSFORM_KINDS, coefficient_matrix, dct2_matrix,
                          dft_matrix, dht_matrix, dwht_matrix,
                          inverse_coefficient_matrix)
@@ -23,8 +23,8 @@ from .layers import (apply_dxt3d_layer, apply_triada_dense,
                      make_mixer_coeffs)
 
 __all__ = [
-    "PAREN_ORDERS", "dxt3d", "gemt3", "gemt3_outer", "gemt3_planned",
-    "macs", "mode_product", "time_steps",
+    "PAREN_ORDERS", "clear_coefficient_cache", "dxt3d", "gemt3",
+    "gemt3_outer", "gemt3_planned", "macs", "mode_product", "time_steps",
     "TRANSFORM_KINDS", "coefficient_matrix", "dct2_matrix", "dft_matrix",
     "dht_matrix", "dwht_matrix", "inverse_coefficient_matrix",
     "EsopStats", "accumulation_error", "block_nonzero_mask", "energy_joules",

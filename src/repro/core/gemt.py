@@ -23,6 +23,7 @@ C_s[n_s, k_s]; the forward transform is ẍ = Σ x·C1[n1,k1]·C2[n2,k2]·C3[n3,
 from __future__ import annotations
 
 import itertools
+from collections import OrderedDict
 from typing import Sequence
 
 import jax
@@ -34,6 +35,7 @@ __all__ = [
     "gemt3_outer",
     "gemt3_planned",
     "dxt3d",
+    "clear_coefficient_cache",
     "macs",
     "time_steps",
     "PAREN_ORDERS",
@@ -167,6 +169,47 @@ def gemt3_planned(
     return _planned(x, c1, c2, c3, out=out, **engine_kwargs)
 
 
+# dxt3d's coefficient matrices, kept across calls: a hit returns the same
+# array object, so the engine's identity-keyed memos (fingerprints, ESOP
+# schedules, transposes) hit as well.  The public builders keep returning
+# fresh arrays, which callers may own and donate.  The key holds no dtype:
+# each kind's builder fixes its dtype.
+_COEFF_CACHE_SIZE = 32
+_COEFF_CACHE: "OrderedDict[tuple, jax.Array]" = OrderedDict()
+
+
+def clear_coefficient_cache() -> None:
+    """Drop the coefficient matrices ``dxt3d`` keeps across calls."""
+    _COEFF_CACHE.clear()
+
+
+def _dxt_coefficients(kind: str, n: int, inverse: bool,
+                      traced: bool) -> jax.Array:
+    """``dxt3d``'s coefficient matrix for one mode, from a bounded LRU cache
+    keyed on ``(kind, n, inverse, default device)``.  Under an outer trace
+    (``traced``) it is built afresh and the cache is neither read nor
+    written; a tracer is never stored."""
+    from ..obs import metrics as _metrics
+    from .transforms import coefficient_matrix, inverse_coefficient_matrix
+
+    build = inverse_coefficient_matrix if inverse else coefficient_matrix
+    if traced:
+        return build(kind, n)
+    key = (kind.lower(), n, inverse, jax.config.jax_default_device)
+    c = _COEFF_CACHE.get(key)
+    if c is not None:
+        _COEFF_CACHE.move_to_end(key)
+        _metrics.inc("dxt3d.coeff_cache.hits")
+        return c
+    _metrics.inc("dxt3d.coeff_cache.misses")
+    c = build(kind, n)
+    if not isinstance(c, jax.core.Tracer):
+        _COEFF_CACHE[key] = c
+        if len(_COEFF_CACHE) > _COEFF_CACHE_SIZE:
+            _COEFF_CACHE.popitem(last=False)
+    return c
+
+
 def dxt3d(
     x: jnp.ndarray,
     kind: str = "dct",
@@ -185,9 +228,12 @@ def dxt3d(
     dispatch, and ``x`` may carry a leading batch axis; ``engine_kwargs``
     (e.g. ``autotune=True``, ``with_info=True``, or ``differentiable=True``
     for a ``jax.grad``-safe engine-lowered backward pass) pass through.
+
+    The coefficient matrices are kept across eager calls (see
+    :func:`clear_coefficient_cache`); under an outer trace (``x`` a tracer)
+    they are built afresh, as traced values.
     """
     from ..obs import trace as _trace
-    from .transforms import coefficient_matrix, inverse_coefficient_matrix
 
     sp = _trace.NULL_SPAN
     if _trace.enabled():
@@ -195,7 +241,6 @@ def dxt3d(
                          {"kind": kind, "inverse": bool(inverse),
                           "engine": bool(engine), "shape": tuple(x.shape)})
     with sp:
-        build = inverse_coefficient_matrix if inverse else coefficient_matrix
         n1, n2, n3 = x.shape[-3:] if engine else x.shape
         sp_c = _trace.NULL_SPAN
         if _trace.enabled():
@@ -203,7 +248,9 @@ def dxt3d(
                                {"kind": kind, "inverse": bool(inverse),
                                 "sizes": (n1, n2, n3)})
         with sp_c:
-            c1, c2, c3 = build(kind, n1), build(kind, n2), build(kind, n3)
+            traced = isinstance(x, jax.core.Tracer)
+            c1, c2, c3 = (_dxt_coefficients(kind, n, bool(inverse), traced)
+                          for n in (n1, n2, n3))
         if jnp.iscomplexobj(c1) and not jnp.iscomplexobj(x):
             x = x.astype(c1.dtype)
         if engine:

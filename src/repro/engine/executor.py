@@ -33,6 +33,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
+from ..core.gemt import clear_coefficient_cache
 from ..kernels import ops
 from ..kernels.ops import _memo_sink
 from ..memo import ArrayMemo
@@ -136,6 +137,10 @@ def _fingerprint(c: jnp.ndarray) -> str:
 
 
 def clear_plan_cache() -> None:
+    """Drop every cached plan and program, and ``dxt3d``'s coefficient
+    matrices: a kept matrix would keep the identity-keyed memos downstream
+    of it (fingerprints, ESOP schedules) warm across a cold start."""
+    clear_coefficient_cache()
     _PLAN_CACHE.clear()
     _ADJ_PLAN_CACHE.clear()
     _CHAIN_PLAN_CACHE.clear()

@@ -6,10 +6,12 @@ import jax
 import jax.numpy as jnp
 from _hypothesis_compat import given, settings, st
 
-from repro.core import (PAREN_ORDERS, coefficient_matrix, dxt3d, gemt3,
-                        gemt3_outer, hosvd, inverse_coefficient_matrix, macs,
-                        mode_product, time_steps, tucker_compress,
-                        tucker_expand, tucker_roundtrip_error)
+from repro.core import (PAREN_ORDERS, clear_coefficient_cache,
+                        coefficient_matrix, dxt3d, gemt3, gemt3_outer, hosvd,
+                        inverse_coefficient_matrix, macs, mode_product,
+                        time_steps, tucker_compress, tucker_expand,
+                        tucker_roundtrip_error)
+from repro.core import gemt as gemt_mod
 
 RNG = np.random.default_rng(0)
 
@@ -116,6 +118,63 @@ class TestTransforms:
         np.testing.assert_allclose(dxt3d(a * x + y, "dct"),
                                    a * dxt3d(x, "dct") + dxt3d(y, "dct"),
                                    rtol=2e-3, atol=2e-4)
+
+
+class TestCoefficientCache:
+    """``dxt3d`` keeps its coefficient matrices across eager calls."""
+
+    @pytest.mark.parametrize("inverse", [False, True])
+    @pytest.mark.parametrize("kind", ["dft", "dht", "dct", "dwht"])
+    def test_cached_matrices_are_the_builders_bits(self, kind, inverse):
+        x = _rand(8, 4, 16)
+        build = inverse_coefficient_matrix if inverse else coefficient_matrix
+        cs = [build(kind, n) for n in x.shape]
+        xc = x.astype(cs[0].dtype)
+        want = np.asarray(gemt3(xc, *cs))
+        clear_coefficient_cache()
+        for _ in range(2):  # a miss, then a hit
+            got = np.asarray(dxt3d(x, kind, inverse=inverse))
+            assert got.dtype == want.dtype
+            np.testing.assert_array_equal(got, want)
+
+    def test_bounded_lru(self):
+        clear_coefficient_cache()
+        for n in range(2, 42):
+            dxt3d(_rand(n, 1, 1), "dct")
+        assert len(gemt_mod._COEFF_CACHE) == gemt_mod._COEFF_CACHE_SIZE
+        assert ("dct", 41, False, None) in gemt_mod._COEFF_CACHE
+        assert ("dct", 1, False, None) in gemt_mod._COEFF_CACHE  # kept hot
+        assert ("dct", 2, False, None) not in gemt_mod._COEFF_CACHE
+
+    def test_outer_traces_neither_read_nor_store_the_cache(self):
+        """Jitted and differentiated calls, before and after an eager call
+        of the same shape, build traced matrices afresh: no tracer leaks
+        into the cache and the results match the eager call."""
+        x = _rand(8, 8, 8)
+        clear_coefficient_cache()
+
+        def traced_calls():  # fresh functions, so each call retraces
+            y = jax.jit(lambda x: dxt3d(x, "dct", engine=True))(x)
+            g = jax.grad(lambda x: jnp.sum(
+                dxt3d(x, "dct", engine=True, differentiable=True) ** 2))(x)
+            const = jax.jit(lambda: dxt3d(x, "dct", engine=True))()
+            return y, g, const
+
+        before = traced_calls()
+        assert not gemt_mod._COEFF_CACHE  # the closed-over call: not stored
+        eager = dxt3d(x, "dct", engine=True)
+        assert len(gemt_mod._COEFF_CACHE) == 1
+        after = traced_calls()
+        for y, g, const in (before, after):
+            np.testing.assert_allclose(y, eager, atol=1e-5, rtol=1e-5)
+            np.testing.assert_allclose(const, eager, atol=1e-5, rtol=1e-5)
+            # orthonormal DCT: d/dx sum((C x)^2) = 2x
+            np.testing.assert_allclose(g, 2 * x, atol=1e-5, rtol=1e-5)
+        np.testing.assert_array_equal(before[1], after[1])
+        assert not any(isinstance(c, jax.core.Tracer)
+                       for c in gemt_mod._COEFF_CACHE.values())
+        assert all(isinstance(c, jax.Array)
+                   for c in gemt_mod._COEFF_CACHE.values())
 
 
 class TestTucker:

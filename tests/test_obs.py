@@ -150,7 +150,7 @@ def test_profiler_session_records_engine_spans_on_its_clock(tmp_path):
     from repro.core import dxt3d
 
     x = _rand(16, 16, 16)
-    clear_plan_cache()
+    clear_plan_cache()  # also drops dxt3d's cached coefficient matrices
     with obs.session(enable_tracing=False) as s:
         assert not obs.enabled()
         jax.profiler.start_trace(str(tmp_path))
@@ -166,7 +166,8 @@ def test_profiler_session_records_engine_spans_on_its_clock(tmp_path):
     for want in ("dxt3d:dht", "dxt3d.coefficients", "plan.fingerprint",
                  "execute"):
         assert want in names
-    assert names.count("plan.fingerprint") == 3
+    # the three modes share one cached matrix: one fingerprint miss
+    assert names.count("plan.fingerprint") == 1
     (root,) = [sp for sp in spans if sp.parent_id == 0]
     assert root.name == "dxt3d:dht"
     assert {sp.root_id for sp in spans} == {root.span_id}
@@ -225,11 +226,12 @@ def test_enabled_and_span_follow_one_rule(monkeypatch, tracer, hook,
 
 def test_fingerprint_span_only_on_memo_miss():
     """Fingerprints are memoized on array identity: a second call with the
-    same matrices records no ``plan.fingerprint``; ``dxt3d`` builds new
-    matrices each call and so misses on all three every time."""
-    from repro.core import dxt3d
+    same matrices records no ``plan.fingerprint``; ``dxt3d`` hands the
+    engine its cached matrices, so after its first call it misses on none."""
+    from repro.core import clear_coefficient_cache, dxt3d
 
     x, c1, c2, c3 = _problem()
+    clear_coefficient_cache()
     with obs.session() as s:
         gemt3_planned(x, c1, c2, c3)
         first = [sp for sp in s.tracer.spans()
@@ -238,14 +240,42 @@ def test_fingerprint_span_only_on_memo_miss():
         gemt3_planned(x, c1, c2, c3)
         assert [sp.name for sp in s.tracer.spans()
                 if sp.name == "plan.fingerprint"] == []
+        fps = []
         for _ in range(2):
             s.tracer.clear()
             dxt3d(x, "dht", inverse=True, engine=True)
-            fps = [sp for sp in s.tracer.spans()
-                   if sp.name == "plan.fingerprint"]
-            assert len(fps) == 3
+            fps.append(sum(1 for sp in s.tracer.spans()
+                           if sp.name == "plan.fingerprint"))
+        assert fps == [1, 0]  # one matrix serves all three 16-wide modes
     assert len(first) == 3
     assert first[0].attrs == {"shape": (16, 16), "dtype": "float32"}
+
+
+def test_dxt3d_second_call_hits_the_coefficient_cache():
+    """A second eager ``dxt3d`` reuses its three coefficient matrices: the
+    cache counts three hits, and no ``plan.fingerprint`` or ``esop.plan``
+    span fires because the identity-keyed memos hit too."""
+    from repro.core import clear_coefficient_cache, dxt3d
+
+    x = _rand(16, 8, 24)  # three distinct keys
+    clear_coefficient_cache()
+    with obs.session() as s:
+        reg = s.registry
+        first = dxt3d(x, "dht", inverse=True, engine=True)
+        assert reg.value("dxt3d.coeff_cache.misses") == 3
+        assert reg.value("dxt3d.coeff_cache.hits") == 0
+        first_names = [sp.name for sp in s.tracer.spans()]
+        s.tracer.clear()
+        second = dxt3d(x, "dht", inverse=True, engine=True)
+        assert reg.value("dxt3d.coeff_cache.misses") == 3
+        assert reg.value("dxt3d.coeff_cache.hits") == 3
+        names = [sp.name for sp in s.tracer.spans()]
+    assert first_names.count("plan.fingerprint") == 3
+    assert "esop.plan" in first_names
+    assert "dxt3d.coefficients" in names
+    assert "plan.fingerprint" not in names
+    assert "esop.plan" not in names
+    np.testing.assert_array_equal(np.asarray(first), np.asarray(second))
 
 
 # ---------------------------------------------------------------------------
