@@ -22,6 +22,7 @@ C_s[n_s, k_s]; the forward transform is ẍ = Σ x·C1[n1,k1]·C2[n2,k2]·C3[n3,
 """
 from __future__ import annotations
 
+import functools
 import itertools
 from collections import OrderedDict
 from typing import Sequence
@@ -173,7 +174,9 @@ def gemt3_planned(
 # array object, so the engine's identity-keyed memos (fingerprints, ESOP
 # schedules, transposes) hit as well.  The public builders keep returning
 # fresh arrays, which callers may own and donate.  The key holds no dtype:
-# each kind's builder fixes its dtype.
+# each kind's builder fixes its dtype.  32 entries hold a 4:2:0 video
+# buffer's blocked factors (both directions of five extents) beside a few
+# unblocked transforms' pairs.
 _COEFF_CACHE_SIZE = 32
 _COEFF_CACHE: "OrderedDict[tuple, jax.Array]" = OrderedDict()
 
@@ -183,31 +186,55 @@ def clear_coefficient_cache() -> None:
     _COEFF_CACHE.clear()
 
 
-def _dxt_coefficients(kind: str, n: int, inverse: bool,
-                      traced: bool) -> jax.Array:
+def _dxt_coefficients(kind: str, n: int, inverse: bool, traced: bool,
+                      block: int | None = None) -> jax.Array:
     """``dxt3d``'s coefficient matrix for one mode, from a bounded LRU cache
-    keyed on ``(kind, n, inverse, default device)``.  Under an outer trace
-    (``traced``) it is built afresh and the cache is neither read nor
-    written; a tracer is never stored."""
+    keyed on ``(kind, n, inverse, default device)``, or on ``(kind, n,
+    block, inverse, default device)`` for the block-diagonal factor
+    ``I_{n/block} ⊗ C_block``.  Under an outer trace (``traced``) it is
+    built afresh and the cache is neither read nor written; a tracer is
+    never stored."""
     from ..obs import metrics as _metrics
-    from .transforms import coefficient_matrix, inverse_coefficient_matrix
+    from .transforms import (blocked_coefficient_matrix, coefficient_matrix,
+                             inverse_coefficient_matrix)
 
-    build = inverse_coefficient_matrix if inverse else coefficient_matrix
+    device = jax.config.jax_default_device
+    if block is None:
+        key = (kind.lower(), n, inverse, device)
+        build = functools.partial(
+            inverse_coefficient_matrix if inverse else coefficient_matrix,
+            kind, n)
+    else:
+        key = (kind.lower(), n, block, inverse, device)
+        build = functools.partial(blocked_coefficient_matrix, kind, n, block,
+                                  inverse=inverse)
     if traced:
-        return build(kind, n)
-    key = (kind.lower(), n, inverse, jax.config.jax_default_device)
+        return build()
     c = _COEFF_CACHE.get(key)
     if c is not None:
         _COEFF_CACHE.move_to_end(key)
         _metrics.inc("dxt3d.coeff_cache.hits")
         return c
     _metrics.inc("dxt3d.coeff_cache.misses")
-    c = build(kind, n)
+    c = build()
     if not isinstance(c, jax.core.Tracer):
         _COEFF_CACHE[key] = c
         if len(_COEFF_CACHE) > _COEFF_CACHE_SIZE:
             _COEFF_CACHE.popitem(last=False)
     return c
+
+
+def _mode_blocks(block: Sequence[int], sizes) -> tuple:
+    """Per-mode block sizes of ``dxt3d(block=...)``: ``None`` where the
+    block spans the whole extent (the unblocked matrix)."""
+    block = tuple(int(b) for b in block)
+    if len(block) != 3 or min(block) < 1:
+        raise ValueError(f"block must be three positive sizes, got {block}")
+    for n, b in zip(sizes, block):
+        if n % b:
+            raise ValueError(
+                f"block {block} does not divide the extents {tuple(sizes)}")
+    return tuple(None if b == n else b for n, b in zip(sizes, block))
 
 
 def dxt3d(
@@ -218,6 +245,7 @@ def dxt3d(
     out: jnp.ndarray | None = None,
     outer: bool = False,
     engine: bool = False,
+    block: Sequence[int] | None = None,
     **engine_kwargs,
 ) -> jnp.ndarray:
     """Forward/inverse separable 3D discrete orthogonal transform (Eq. 1/2).
@@ -229,6 +257,13 @@ def dxt3d(
     (e.g. ``autotune=True``, ``with_info=True``, or ``differentiable=True``
     for a ``jax.grad``-safe engine-lowered backward pass) pass through.
 
+    ``block=(b1, b2, b3)`` applies the kind's b-point transforms to every
+    b1×b2×b3 block of the last three axes (the 3-D DCT block coding of
+    video): each mode's coefficient matrix is then the block-diagonal
+    ``I_{N/b} ⊗ C_b``, whose zero blocks the engine's block-ESOP stages
+    skip.  A block must divide its extent (``ValueError`` otherwise); a
+    block spanning the whole extent is that mode's unblocked transform.
+
     The coefficient matrices are kept across eager calls (see
     :func:`clear_coefficient_cache`); under an outer trace (``x`` a tracer)
     they are built afresh, as traced values.
@@ -239,9 +274,13 @@ def dxt3d(
     if _trace.enabled():
         sp = _trace.span(f"dxt3d:{kind}",
                          {"kind": kind, "inverse": bool(inverse),
-                          "engine": bool(engine), "shape": tuple(x.shape)})
+                          "engine": bool(engine), "shape": tuple(x.shape),
+                          "block": None if block is None else tuple(block)})
     with sp:
-        n1, n2, n3 = x.shape[-3:] if engine else x.shape
+        sizes = x.shape[-3:] if engine else x.shape
+        n1, n2, n3 = sizes
+        blocks = (None, None, None) if block is None else _mode_blocks(
+            block, sizes)
         sp_c = _trace.NULL_SPAN
         if _trace.enabled():
             sp_c = _trace.span("dxt3d.coefficients",
@@ -249,8 +288,9 @@ def dxt3d(
                                 "sizes": (n1, n2, n3)})
         with sp_c:
             traced = isinstance(x, jax.core.Tracer)
-            c1, c2, c3 = (_dxt_coefficients(kind, n, bool(inverse), traced)
-                          for n in (n1, n2, n3))
+            c1, c2, c3 = (_dxt_coefficients(kind, n, bool(inverse), traced,
+                                            b)
+                          for n, b in zip((n1, n2, n3), blocks))
         if jnp.iscomplexobj(c1) and not jnp.iscomplexobj(x):
             x = x.astype(c1.dtype)
         if engine:

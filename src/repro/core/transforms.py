@@ -28,6 +28,7 @@ __all__ = [
     "dwht_matrix",
     "coefficient_matrix",
     "inverse_coefficient_matrix",
+    "blocked_coefficient_matrix",
     "TRANSFORM_KINDS",
 ]
 
@@ -100,3 +101,20 @@ def inverse_coefficient_matrix(kind: str, n: int, dtype=None) -> jnp.ndarray:
     """Inverse = conjugate transpose (orthonormal builders)."""
     c = coefficient_matrix(kind, n, dtype=dtype)
     return jnp.conj(c).T
+
+
+def blocked_coefficient_matrix(kind: str, n: int, block: int, *,
+                               inverse: bool = False,
+                               dtype=None) -> jnp.ndarray:
+    """Block-diagonal ``I_{n/block} ⊗ C_block``: the kind's ``block``-point
+    transform applied to each run of ``block`` consecutive indices (the
+    forward matrix, or with ``inverse`` its inverse — the blockwise
+    conjugate transpose).  ``block`` must divide ``n``."""
+    if block < 1 or n % block:
+        raise ValueError(f"block {block} does not divide extent {n}")
+    build = inverse_coefficient_matrix if inverse else coefficient_matrix
+    cb = np.asarray(build(kind, block, dtype=dtype))
+    m = n // block
+    out = np.zeros((m, block, m, block), cb.dtype)
+    out[np.arange(m), :, np.arange(m), :] = cb
+    return jnp.asarray(out.reshape(n, n))

@@ -440,6 +440,7 @@ def _record_execution(info: dict) -> None:
     reg = _metrics.get_registry()
     reg.inc("engine.executions")
     reg.inc("engine.macs", info["macs"])
+    reg.inc("engine.macs_effective", info["macs_effective"])
     reg.inc("engine.hbm_bytes_moved", info["hbm_bytes_moved"])
     reg.inc("engine.hbm_bytes_staged", info["hbm_bytes_staged"])
     reg.inc("engine.collective_bytes", info["collective_bytes"])

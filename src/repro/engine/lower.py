@@ -17,6 +17,8 @@ schedule with the planned Pallas kernels doing the local work.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -81,6 +83,26 @@ def mode_fold(y2d: jnp.ndarray, lead_shape: tuple[int, ...], mode: int) -> jnp.n
     return jnp.moveaxis(y, -1, ax)
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "mode", "backend", "bm", "bn", "bk", "t_steps", "accum", "use_pallas"))
+def _kernel_stage(x, c, counts, idx, *, mode, backend, bm, bn, bk, t_steps,
+                  accum, use_pallas):
+    """unfold → kernel → fold as one program.  Run eagerly, each step
+    (transpose, reshape, pad, crop, the fold's reshape and transpose)
+    materializes a copy of the tensor; compiled together, the reshapes and
+    the crop become bitcasts, and only the transposes and the operand's
+    padding are written."""
+    x2d, lead = mode_unfold(x, mode)
+    if backend == "esop":
+        y2d, _ = ops.esop_gemm(x2d, c, bm=bm, bn=bn, bk=bk,
+                               use_pallas=use_pallas,
+                               plan=(counts, idx, t_steps, {}), accum=accum)
+    else:
+        y2d = ops.sr_gemm(x2d, c, bm=bm, bn=bn, bk=bk,
+                          use_pallas=use_pallas, accum=accum)
+    return mode_fold(y2d, lead, mode)
+
+
 def lower_stage(
     x: jnp.ndarray,
     c: jnp.ndarray,
@@ -104,26 +126,24 @@ def lower_stage(
                          {"mode": stage.mode, "backend": stage.backend,
                           "macs": stage.macs, "shape": tuple(x.shape)})
     with sp:
-        if stage.backend == "einsum":
-            rows = x.size // max(x.shape[x.ndim - 3 + stage.mode - 1], 1)
-            info = {"mode": stage.mode, "backend": "einsum",
-                    "rows": int(rows), "macs": stage.macs}
-            return _einsum_stage(x, c, stage.mode, stage.accum), info
-        x2d, lead = mode_unfold(x, stage.mode)
+        rows = x.size // max(x.shape[x.ndim - 3 + stage.mode - 1], 1)
         info: dict = {"mode": stage.mode, "backend": stage.backend,
-                      "rows": int(x2d.shape[0]), "macs": stage.macs}
+                      "rows": int(rows), "macs": stage.macs}
+        if stage.backend == "einsum":
+            return _einsum_stage(x, c, stage.mode, stage.accum), info
         if stage.backend == "esop":
-            y2d, esop_info = ops.esop_gemm(x2d, c, bm=stage.bm, bn=stage.bn,
-                                           bk=stage.bk, use_pallas=use_pallas,
-                                           plan=esop_plan,
-                                           accum=stage.accum)
-            info.update(esop_info)
+            plan = (esop_plan if esop_plan is not None
+                    else ops.esop_plan_cached(c, stage.bk, stage.bn))
+            info.update(plan[3])
         elif stage.backend == "sr_gemm":
-            y2d = ops.sr_gemm(x2d, c, bm=stage.bm, bn=stage.bn, bk=stage.bk,
-                              use_pallas=use_pallas, accum=stage.accum)
+            plan = (None, None, 0)
         else:
             raise ValueError(f"unknown backend {stage.backend!r}")
-        return mode_fold(y2d, lead, stage.mode), info
+        y = _kernel_stage(x, c, plan[0], plan[1], mode=stage.mode,
+                          backend=stage.backend, bm=stage.bm, bn=stage.bn,
+                          bk=stage.bk, t_steps=plan[2], accum=stage.accum,
+                          use_pallas=use_pallas)
+        return y, info
 
 
 def lower_sharded_stage(

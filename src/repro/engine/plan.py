@@ -18,8 +18,10 @@ chooses a per-stage backend from the coefficient matrix's *block* sparsity
                   kernel skips fetching/multiplying those blocks, so the
                   stage's effective MACs scale by the live-block fraction;
   * ``sr_gemm`` — dense streaming outer-product kernel;
-  * ``einsum``  — fallback for complex dtypes (DFT) and tiny operands where
-                  kernel/padding overhead dominates.
+  * ``einsum``  — fallback for complex dtypes (DFT), tiny operands where
+                  kernel/padding overhead dominates, and modes narrower
+                  than a lane in a tensor whose minor mode is lane-wide
+                  (the kernels' unfold would pad them in HBM).
 
 ``build_plan`` is pure and host-side: it never touches device values beyond
 reading the coefficient matrices' zero structure.
@@ -150,6 +152,15 @@ def lane_tile_ok(tile: int, extent: int) -> bool:
     least the extent is one block spanning the whole padded dim.
     """
     return tile % LANE == 0 or tile >= extent
+
+
+def _lane_padded(extent: int, lane_extent: int) -> bool:
+    """True when a kernel layout would put ``extent``, narrower than a
+    lane, into the minor dim of an operand whose tensor keeps a lane-wide
+    minor extent (``lane_extent``).  HBM tiles the minor dim to 128 lanes,
+    so that operand would take LANE/extent times the tensor's bytes, where
+    an in-place einsum keeps its lane-dense layout."""
+    return lane_extent >= LANE and extent < LANE
 
 
 def _lane_tile(d: int, seed: int | None = None) -> int:
@@ -435,6 +446,7 @@ def _plan_stage(
     axis: AxisName = None,
     shards: int = 1,
     itemsize_total: int = 4,
+    lane_extent: int = 0,
 ) -> StagePlan:
     n, k = c.shape
     if shards > 1:
@@ -492,7 +504,12 @@ def _plan_stage(
             mask_cache[mode] = mask
     zero_frac = 1.0 - float(mask.mean()) if mask.size else 0.0
 
-    if min(rows_total, n, k) < MIN_KERNEL_DIM:
+    # A kernel stage unfolds its mode into the minor dim of its input and
+    # output, where the tensor keeps ``lane_extent`` (mode 3 is there
+    # already, and its output's minor extent is k).
+    if (min(rows_total, n, k) < MIN_KERNEL_DIM
+            or _lane_padded(n, lane_extent)
+            or _lane_padded(k, k if mode == 3 else lane_extent)):
         backend = "einsum"
         eff = dense_macs
     elif zero_frac >= esop_threshold:
@@ -535,7 +552,7 @@ def _plan_for_order(
                          esop_threshold=esop_threshold,
                          block_sizes=block_sizes, mask_cache=mask_cache,
                          axis=axes[mode - 1], shards=shards[mode - 1],
-                         itemsize_total=itemsize)
+                         itemsize_total=itemsize, lane_extent=d[2])
         stages.append(st)
         # A sharded stage materializes the full-K_s partial before the
         # scatter shrinks it to K_s/P local — that partial is the stage's
@@ -931,6 +948,9 @@ def _plan_fusion3(
         nc, kc = cc.shape
         if min(na, ka, nb, kb, nc, kc) < MIN_KERNEL_DIM:
             continue  # padding overhead beats the kernel
+        if not force and (_lane_padded(na, cs[3].shape[0])
+                          or _lane_padded(kc, cs[3].shape[1])):
+            continue  # X4's minor dim is na, Y's is kc
         st_a = stage_of[mode_a]
         tiles = fused3_tile_sizes(
             rows_total, na, ka, nb, kb, nc, kc, itemsize, vmem_budget,
@@ -1052,6 +1072,9 @@ def _plan_fusion(
         nb, kb = cb.shape
         if min(rows_total, na, ka, nb, kb) < MIN_KERNEL_DIM:
             continue  # padding overhead beats the kernel, as for single stages
+        if not force and (_lane_padded(na, d[2]) or _lane_padded(
+                kb, cs[3].shape[1] if 3 in pair else d[2])):
+            continue  # X3's minor dim is na, Y's is kb
         # For *sparse* coefficients, seed the streamed-side grid from the
         # staged stage's ESOP blocks so the fused mask sees the same zero
         # structure the planner scored; dense stages take the pow2-ceil

@@ -156,9 +156,15 @@ class TestLowering:
                                    rtol=1e-4, atol=1e-4)
         assert "esop" in info["backends"]
         assert info["fetch_savings"] > 0
-        # the default (auto-fusion) schedule stays numerically identical
+        # the default (auto-fusion) schedule stays numerically identical, up
+        # to float32 rounding: a schedule that reassociates the 128-deep
+        # sums moves outputs of magnitude ~850 by ~4e-4 (a skipped live
+        # block would move them by ~100), so the absolute tolerance is the
+        # plan's a-priori rounding bound at the output's scale
         yf = gemt3_planned(x, c1, c2, c3, block_sizes=(128, 32, 32))
-        np.testing.assert_allclose(yf, y, rtol=1e-4, atol=1e-4)
+        scale = float(jnp.max(jnp.abs(y)))
+        np.testing.assert_allclose(
+            yf, y, rtol=1e-4, atol=info["numerics"]["error_bound"] * scale)
 
     def test_pruned_sparse_matches_oracle(self):
         x, cs = _rect_problem((32, 32, 32), (16, 16, 16), seed=2)

@@ -11,6 +11,7 @@ time: only one process may load the TPU library, and every test worker
 imports every test file.
 """
 import functools
+import math
 import os
 
 import jax
@@ -19,7 +20,8 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-from repro.core.transforms import coefficient_matrix
+from repro.core.transforms import (blocked_coefficient_matrix,
+                                   coefficient_matrix)
 from repro.engine.plan import (build_plan, derive_adjoint_plan,
                                lane_tile_ok, plan_adjoint_chain)
 from repro.kernels.esop_gemm import esop_gemm_pallas, esop_plan
@@ -237,6 +239,46 @@ def test_block_sparse_esop_gemm_compiles(one_chip):
     _compile(fn, one_chip, ((_pad(m, s.bm), n), jnp.float32),
              ((n, n), jnp.float32), (counts.shape, jnp.int32),
              (idx.shape, jnp.int32))
+
+
+# The planes of one 4K 4:2:0 video buffer (32 frames), coded in 8x8x8
+# blocks: luma and each chroma plane.
+VIDEO_PLANES = [(32, 2160, 3840), (32, 1080, 1920)]
+
+
+@pytest.mark.parametrize("shape", VIDEO_PLANES,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_blocked_video_plane_compiles(one_chip, shape):
+    """The blocked DCT of a video plane: the long modes' block-diagonal
+    factors plan block-ESOP, whose kernels compile at the planner's tiles
+    with the factors' own skip schedules; the 32-frame mode runs einsum
+    in place, which keeps the plane's lane-dense layout (no padded copy)."""
+    cs = [blocked_coefficient_matrix("dct", n, 8) for n in shape]
+    plan = build_plan(shape, jnp.float32, *cs)
+    _assert_lane_rule(plan)
+    backend = {s.mode: s.backend for s in plan.stages}
+    assert backend == {1: "einsum", 2: "esop", 3: "esop"}, plan.stages
+    assert plan.fused is None and plan.fused3 is None, plan.events
+    for s in plan.stages:
+        if s.backend != "esop":
+            continue
+        cp = np.pad(np.asarray(cs[s.mode - 1]),
+                    ((0, _pad(s.n, s.bk) - s.n), (0, _pad(s.k, s.bn) - s.k)))
+        counts, idx, t_steps = esop_plan(jnp.asarray(cp), s.bk, s.bn)
+        assert t_steps == 1  # one live 128-block per column: 8-blocks align
+
+        def fn(x, c, counts, idx, s=s, t_steps=t_steps):
+            return esop_gemm_pallas(x, c, None, bm=s.bm, bn=s.bn, bk=s.bk,
+                                    plan=(counts, idx, t_steps))[0]
+
+        _compile(fn, one_chip,
+                 ((_pad(s.rows, s.bm), cp.shape[0]), jnp.float32),
+                 (cp.shape, jnp.float32), (counts.shape, jnp.int32),
+                 (idx.shape, jnp.int32))
+    t = _compile(lambda x, c: jnp.einsum("abc,ax->xbc", x, c), one_chip,
+                 (shape, jnp.float32), ((32, 32), jnp.float32))
+    mem = t.memory_analysis()
+    assert mem.output_size_in_bytes == 4 * math.prod(shape), mem
 
 
 FIT_SHAPE = (8, 128, 128, 128)  # the fit step's batch of 8 128³ volumes
