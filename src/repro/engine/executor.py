@@ -441,6 +441,7 @@ def _record_execution(info: dict) -> None:
     reg.inc("engine.executions")
     reg.inc("engine.macs", info["macs"])
     reg.inc("engine.macs_effective", info["macs_effective"])
+    reg.inc("engine.grid_steps", info["grid_steps"])
     reg.inc("engine.hbm_bytes_moved", info["hbm_bytes_moved"])
     reg.inc("engine.hbm_bytes_staged", info["hbm_bytes_staged"])
     reg.inc("engine.collective_bytes", info["collective_bytes"])
@@ -481,6 +482,8 @@ def _assemble_info(plan: GemtPlan, stage_infos: list[dict]) -> dict:
              else i["backend"]) for i in stage_infos),
         "macs": plan.macs,
         "macs_effective": plan.macs_effective,
+        # Pallas grid steps of the launches (fused ones count their own)
+        "grid_steps": sum(i["grid_steps"] for i in stage_infos),
         "stages": stage_infos,
         "fused": fused_info,
         "axes": plan.axes,

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from ..core import gemt as _gemt
 from ..kernels import ops
 from ..obs import trace as _trace
-from .plan import FusedPairPlan, FusedTriplePlan, StagePlan
+from .plan import FusedPairPlan, FusedTriplePlan, StagePlan, _ceil_div
 
 __all__ = ["mode_unfold", "mode_fold", "lower_stage", "lower_fused_pair",
            "lower_fused_triple", "lower_chain_pair", "lower_chain_triple",
@@ -103,6 +103,14 @@ def _kernel_stage(x, c, counts, idx, *, mode, backend, bm, bn, bk, t_steps,
     return mode_fold(y2d, lead, mode)
 
 
+def _stage_grid_steps(rows: int, stage: StagePlan, t_steps: int) -> int:
+    """Grid steps of a staged kernel launch over ``rows`` unfolded rows:
+    ``(rows/bm, K/bn, t)`` after padding, where ``t`` is the ESOP
+    schedule's ``t_steps`` or, dense (``t_steps=0``), ``N/bk``."""
+    t = t_steps or _ceil_div(stage.n, stage.bk)
+    return _ceil_div(rows, stage.bm) * _ceil_div(stage.k, stage.bn) * t
+
+
 def lower_stage(
     x: jnp.ndarray,
     c: jnp.ndarray,
@@ -113,9 +121,10 @@ def lower_stage(
 ) -> tuple[jnp.ndarray, dict]:
     """Execute one planned contraction stage.  Returns ``(y, info)``.
 
-    ``info`` carries the backend actually used plus the block-ESOP fetch
-    accounting when that path engages (backend-independent: the reference
-    path reports the same savings the TPU kernel realizes).  ``esop_plan``
+    ``info`` carries the backend actually used, the kernel's
+    ``grid_steps`` (0 on einsum) and the block-ESOP fetch accounting when
+    that path engages (backend-independent: the reference path reports
+    the same steps and savings the TPU kernel realizes).  ``esop_plan``
     optionally supplies the precomputed ``esop_plan_cached`` tuple — the
     distributed executor computes it host-side before entering the
     ``shard_map`` body, where ``c`` is a tracer.
@@ -128,7 +137,8 @@ def lower_stage(
     with sp:
         rows = x.size // max(x.shape[x.ndim - 3 + stage.mode - 1], 1)
         info: dict = {"mode": stage.mode, "backend": stage.backend,
-                      "rows": int(rows), "macs": stage.macs}
+                      "rows": int(rows), "macs": stage.macs,
+                      "grid_steps": 0}
         if stage.backend == "einsum":
             return _einsum_stage(x, c, stage.mode, stage.accum), info
         if stage.backend == "esop":
@@ -139,6 +149,7 @@ def lower_stage(
             plan = (None, None, 0)
         else:
             raise ValueError(f"unknown backend {stage.backend!r}")
+        info["grid_steps"] = _stage_grid_steps(rows, stage, plan[2])
         y = _kernel_stage(x, c, plan[0], plan[1], mode=stage.mode,
                           backend=stage.backend, bm=stage.bm, bn=stage.bn,
                           bk=stage.bk, t_steps=plan[2], accum=stage.accum,
@@ -183,10 +194,12 @@ def lower_sharded_stage(
         info: dict = {"mode": stage.mode, "backend": stage.backend,
                       "rows": int(rows), "macs": stage.macs,
                       "axis": stage.axis, "shards": stage.shards,
-                      "collective_bytes": stage.collective_bytes}
+                      "collective_bytes": stage.collective_bytes,
+                      "grid_steps": 0}
         if stage.backend == "einsum":
             partial = _einsum_stage(x, c_rows, stage.mode, stage.accum)
         elif stage.backend == "sr_gemm":
+            info["grid_steps"] = _stage_grid_steps(rows, stage, 0)
             x2d, lead = mode_unfold(x, stage.mode)
             y2d = ops.sr_gemm(x2d, c_rows, bm=stage.bm, bn=stage.bn,
                               bk=stage.bk, use_pallas=use_pallas,
@@ -327,6 +340,9 @@ def lower_fused_pair(
                   "hbm_bytes_fused": fp.hbm_bytes_fused,
                   "hbm_savings": fp.hbm_savings}
     info.update(kinfo)
+    t_a, t_b = kinfo["t_steps"]
+    info["grid_steps"] = (_ceil_div(x3.shape[0], fp.bu)
+                          * _ceil_div(fp.ka, fp.bka) * t_b * t_a)
     return y, info
 
 
@@ -514,4 +530,7 @@ def lower_fused_triple(
                   "hbm_bytes_fused": ft.hbm_bytes_fused,
                   "hbm_savings": ft.hbm_savings}
     info.update(kinfo)
+    t_a, t_b, t_c = kinfo["t_steps"]
+    info["grid_steps"] = (_ceil_div(x4.shape[0], ft.bu)
+                          * _ceil_div(ft.ka, ft.bka) * t_c * t_b * t_a)
     return y, info

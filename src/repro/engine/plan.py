@@ -79,6 +79,8 @@ __all__ = [
     "chain_vmem_bytes",
     "chain3_tile_sizes",
     "chain3_vmem_bytes",
+    "stage_vmem_bytes",
+    "stage_row_tile",
     "refresh_fused_pair",
     "refresh_fused_triple",
     "stage_hbm_bytes",
@@ -141,7 +143,9 @@ def _pad_up(d: int, b: int) -> int:
 # TPU tiling rule for a Pallas block (Mosaic refuses anything else): the
 # last dim is a multiple of 128 lanes or spans the whole array dim, the
 # second-to-last a multiple of 8 sublanes or the whole dim.  Every tile
-# here is a power of two >= 8, so the sublane half always holds.
+# here is a power of two >= 8 or, for a staged stage's row tile ``bm``
+# (:func:`stage_row_tile`), a multiple of 128, so the sublane half always
+# holds.
 LANE = 128
 
 
@@ -430,7 +434,8 @@ def _stage_blocks(rows: int, n: int, k: int,
     # Default: MXU-aligned 128, shrunk to the pow2 ceiling of small
     # operands so block-sparsity detection and padding stay proportionate.
     # All three are lane-legal (the backward dispatches swap tile roles, so
-    # bm becomes a lane dim there too).
+    # bm becomes a lane dim there too).  build_plan then widens bm from
+    # the VMEM model (stage_row_tile) once the accumulation mode is known.
     return (_lane_tile(rows), _lane_tile(k), _lane_tile(n))
 
 
@@ -610,6 +615,61 @@ def _vmem(shape: tuple[int, ...], itemsize: int) -> int:
     *lead, sub, lane = shape
     rows = 8 * max(1, 4 // itemsize)
     return math.prod(lead) * _pad_up(sub, rows) * _pad_up(lane, LANE) * itemsize
+
+
+def _gemm_vmem_bytes(bm: int, bn: int, bk: int, itemsize: int,
+                    accum: str = "plain") -> int:
+    """Modeled VMEM footprint of one SR-GEMM / block-ESOP launch.
+
+    Counted as :func:`fused_vmem_bytes` counts the pair: double-buffered
+    X ``(bm, bk)``, C ``(bk, bn)`` and output ``(bm, bn)`` blocks, the f32
+    accumulator and the product temporary, plus the Neumaier comp scratch
+    under ``accum="compensated"``, all in the (8, 128)-tiled layout.
+    """
+    out_isz = itemsize if accum == "plain" else 4
+    tile = _vmem((bm, bn), 4)
+    return (2 * _vmem((bm, bk), itemsize)
+            + 2 * _vmem((bk, bn), itemsize)
+            + 2 * _vmem((bm, bn), out_isz)
+            + tile * (3 if accum == "compensated" else 2))
+
+
+def stage_vmem_bytes(bm: int, bn: int, bk: int, itemsize: int,
+                     accum: str = "plain") -> int:
+    """Largest footprint of the three launches a staged stage's tiles run:
+    the forward ``(bm, bn, bk)`` and the VJP's dX ``(bm, bk, bn)`` and dC
+    ``(bk, bn, bm)`` (``kernels/ops.py:_sr_gemm_vjp``), where ``bm`` is the
+    contraction and lane dim.  The backward accumulates plain, on a
+    cotangent that is float32 under a promoted ``accum``."""
+    g_isz = itemsize if accum == "plain" else 4
+    return max(_gemm_vmem_bytes(bm, bn, bk, itemsize, accum),
+               _gemm_vmem_bytes(bm, bk, bn, g_isz),
+               _gemm_vmem_bytes(bk, bn, bm, g_isz))
+
+
+def stage_row_tile(rows: int, batch: int, bn: int, bk: int, itemsize: int,
+                   accum: str = "plain",
+                   vmem_budget: int = DEFAULT_VMEM_BUDGET) -> int:
+    """Row tile ``bm`` of a staged kernel stage over ``rows`` (per sample).
+
+    The largest multiple of 128 that divides ``rows`` and whose
+    :func:`stage_vmem_bytes` fits ``vmem_budget``: each grid step then
+    does as much work as VMEM admits, and the rows need no padding copy at
+    any batch (a bucketed plan runs smaller batches than it was planned
+    for).  Rows that 128 does not divide keep the lane tile of the batched
+    rows, and a budget too small for more keeps 128.  Being a multiple of
+    128, ``bm`` stays lane-legal in the backward, where it is the lane dim.
+    """
+    if rows % LANE:
+        return _lane_tile(rows * max(batch, 1))
+    bm = LANE
+    while (bm + LANE <= rows
+           and stage_vmem_bytes(bm + LANE, bn, bk, itemsize, accum)
+           <= vmem_budget):
+        bm += LANE
+    while rows % bm:
+        bm -= LANE
+    return bm
 
 
 def fused_vmem_bytes(bu: int, bka: int, bnb: int, bna: int, kbp: int,
@@ -1625,6 +1685,13 @@ def build_plan(
         stages = tuple(dataclasses.replace(s, accum=accum) for s in stages)
 
     isz_raw = jnp.dtype(x_dtype).itemsize
+    if block_sizes is None:
+        # Row tiles from the VMEM model, before fusion planning: the
+        # staged byte model counts C once per row block.  (bk, bn), and so
+        # the ESOP masks and the sparsity signature, do not depend on bm.
+        stages = tuple(dataclasses.replace(s, bm=stage_row_tile(
+            s.rows, batch, s.bn, s.bk, isz_raw, accum, vmem_budget))
+            for s in stages)
     fused = None
     fused3 = None
     fusion_events: list[dict] = []  # demotion records, filtered below

@@ -378,6 +378,17 @@ def test_span_tree_lines_indent_children():
 # ---------------------------------------------------------------------------
 
 
+def _assert_grid_steps_parity(reg, infos):
+    """``engine.grid_steps`` is the stages' ``info["grid_steps"]`` summed
+    over the executions, and every kernel launch counts some."""
+    per_call = [sum(si["grid_steps"] for si in i["stages"]) for i in infos]
+    assert per_call == [i["grid_steps"] for i in infos]
+    assert reg.value("engine.grid_steps") == sum(per_call) > 0
+    for i in infos:
+        for si in i["stages"]:
+            assert (si["grid_steps"] > 0) == (si["backend"] != "einsum"), si
+
+
 def _run_and_compare(fuse, n=24):
     x, c1, c2, c3 = _problem(n)
     clear_plan_cache()
@@ -389,6 +400,7 @@ def _run_and_compare(fuse, n=24):
         reg = s.registry
         assert reg.value("engine.executions") == len(infos)
         assert reg.value("engine.macs") == sum(i["macs"] for i in infos)
+        _assert_grid_steps_parity(reg, infos)
         assert (reg.value("engine.hbm_bytes_moved")
                 == sum(i["hbm_bytes_moved"] for i in infos))
         assert (reg.value("engine.hbm_bytes_staged")
@@ -417,6 +429,32 @@ def test_counter_parity_pair():
 def test_counter_parity_triple():
     info = _run_and_compare(fuse="triple")
     assert info["fused"] and len(info["fused"]["modes"]) == 3
+
+
+def test_counter_parity_grid_steps_esop():
+    """Staged block-ESOP stages count the grid they launch: row blocks ×
+    column blocks × the schedule's live steps, not the dense depth."""
+    from repro.core.transforms import blocked_coefficient_matrix
+
+    from repro.engine.plan import build_plan
+
+    x = _rand(16, 256, 256)
+    c1 = _rand(16, 16)
+    c = blocked_coefficient_matrix("dct", 256, 8)
+    clear_plan_cache()
+    with obs.session() as s:
+        infos = [gemt3_planned(x, c1, c, c, fuse=False, with_info=True)[1]
+                 for _ in range(2)]
+        _assert_grid_steps_parity(s.registry, infos)
+    plan = build_plan(x.shape, x.dtype, c1, c, c, fuse=False)
+    tiles = {st.mode: st for st in plan.stages}
+    esop = [si for si in infos[0]["stages"] if si["backend"] == "esop"]
+    assert esop
+    for si in esop:
+        st = tiles[si["mode"]]
+        assert si["t_steps"] == 1 < si["t_steps_dense"]
+        assert st.bm > 128 and si["rows"] % st.bm == 0, st
+        assert si["grid_steps"] == (si["rows"] // st.bm) * (256 // st.bn), si
 
 
 def test_counter_parity_sharded():

@@ -190,10 +190,39 @@ def test_planned_kernels_compile(one_chip, shape):
     for i, s in enumerate(plan.stages):
         if i in covered or s.backend == "einsum":
             continue
+        assert _pad(s.rows * batch, s.bm) == s.rows * batch, s  # no row pad
         compile_gemm(one_chip, s.rows * batch, s.k, s.n, s.bm, s.bn, s.bk,
                      jnp.float32)
         kernel_stages += 1
     assert covered or kernel_stages, "plan selected no Pallas kernel"
+
+
+@pytest.mark.parametrize("role", ["dx", "dc"])
+def test_sr_gemm_vjp_compiles_at_planned_tiles(one_chip, monkeypatch, role):
+    """The VJP of the 512³ plan's staged SR-GEMM at its row tile: dX
+    runs the tiles as ``(bm, bk, bn)`` and dC as ``(bk, bn, bm)``, with
+    ``bm`` the contraction and lane dim, so a row tile the VMEM model
+    admits must compile in both roles."""
+    from repro.kernels import ops
+
+    n = 512
+    plan = build_plan((n, n, n), jnp.float32, *_dct(n, n, n))
+    (s,) = [s for i, s in enumerate(plan.stages)
+            if i not in (plan.fused.first, plan.fused.first + 1)]
+    assert s.backend == "sr_gemm" and s.bm > 128, s
+    # Kernel dispatch asks on_tpu() for interpret mode; this is for a TPU.
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+
+    def fn(x, c, g):
+        f = lambda x, c: ops.sr_gemm(x, c, bm=s.bm, bn=s.bn, bk=s.bk,
+                                     use_pallas=True)
+        dx, dc = jax.vjp(f, x, c)[1](g)
+        return dx if role == "dx" else dc
+
+    hlo = _compile(fn, one_chip, ((s.rows, n), jnp.float32),
+                   ((n, n), jnp.float32),
+                   ((s.rows, n), jnp.float32)).as_text()
+    assert "tpu_custom_call" in hlo
 
 
 @pytest.mark.parametrize("n", [32, 48])
@@ -262,6 +291,7 @@ def test_blocked_video_plane_compiles(one_chip, shape):
     for s in plan.stages:
         if s.backend != "esop":
             continue
+        assert _pad(s.rows, s.bm) == s.rows, s  # no row-padding copy
         cp = np.pad(np.asarray(cs[s.mode - 1]),
                     ((0, _pad(s.n, s.bk) - s.n), (0, _pad(s.k, s.bn) - s.k)))
         counts, idx, t_steps = esop_plan(jnp.asarray(cp), s.bk, s.bn)
