@@ -25,7 +25,9 @@ and ``grad_stats()`` counts the executed backward dispatch.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -41,11 +43,13 @@ from ..obs import metrics as _metrics
 from ..obs import trace as _trace
 from .autotune import (AutotuneCache, autotune_fused, autotune_fused3,
                        autotune_gemm, make_key)
-from .lower import (lower_chain_pair, lower_chain_triple, lower_coeff_grad,
-                    lower_coeff_grad_batch, lower_fused_pair,
-                    lower_fused_triple, lower_sharded_stage, lower_stage)
+from .lower import (coeff_grad_backend, lower_chain_pair, lower_chain_triple,
+                    lower_coeff_grad, lower_coeff_grad_batch,
+                    lower_fused_pair, lower_fused_triple,
+                    lower_sharded_stage, lower_stage)
 from .plan import (DEFAULT_ESOP_THRESHOLD, DEFAULT_VMEM_BUDGET,
-                   AdjointChainPlan, GemtPlan, _is_traced, build_plan,
+                   AdjointChainPlan, FusedPairPlan, FusedTriplePlan,
+                   GemtPlan, StagePlan, _is_traced, build_plan,
                    derive_adjoint_plan, normalize_axes, plan_adjoint_chain,
                    plan_hbm_bytes, refresh_fused_pair, refresh_fused_triple)
 
@@ -66,7 +70,9 @@ _PLAN_CACHE: dict[tuple, GemtPlan] = {}
 _ADJ_PLAN_CACHE: dict[tuple, GemtPlan] = {}  # forward plan key -> adjoint
 _CHAIN_PLAN_CACHE: dict[tuple, AdjointChainPlan] = {}  # backward walk fusion
 _TUNED_PLAN_CACHE: dict[tuple, GemtPlan] = {}  # post-autotune variants
-_SHARDED_FN_CACHE: dict[tuple, tuple] = {}  # plan+cs -> (jitted shard_map, infos)
+# programs: plan+cs -> [jitted shard_map, infos, info]; backward walks
+# ("vjp_walk", plan key, pieces, ...) -> jitted walk
+_SHARDED_FN_CACHE: dict[tuple, object] = {}
 # per-array-identity digests: plan-cache hits stay cheap
 _FP_MEMO = ArrayMemo(on_event=_memo_sink("memo.fingerprint."))
 
@@ -191,16 +197,13 @@ def invalidate_plans(predicate=None, *, mesh=None) -> int:
         for key, adj in list(_ADJ_PLAN_CACHE.items()):
             if key[0] in dropped:
                 del _ADJ_PLAN_CACHE[key]
-                dropped.add(adj.key)  # sharded VJP fns key off the adjoint
+                dropped.add(adj.key)  # tuned adjoint variants key off it
         for key in list(_CHAIN_PLAN_CACHE):
-            if key[0] in dropped or key[1] in dropped:
+            if key[0] in dropped:
                 del _CHAIN_PLAN_CACHE[key]
-        vjp_prefixes = ("vjp_prefix", "vjp_chain", "vjp_rec_chain",
-                        "vjp_adj_chain", "vjp_adj_tail", "vjp_coeff_batch",
-                        "vjp_coeff", "vjp_fused_walk")
         for cache in (_TUNED_PLAN_CACHE, _SHARDED_FN_CACHE):
             for key in list(cache):
-                pk = key[1] if key[0] in vjp_prefixes else key[0]
+                pk = key[1] if key[0] == "vjp_walk" else key[0]
                 if pk in dropped:
                     del cache[key]
     _metrics.inc("plan.invalidations", n)
@@ -375,6 +378,69 @@ def _autotuned_plan(
                                        fused3=fused3))
 
 
+def _launches(plan: GemtPlan) -> list:
+    """The plan's launches in order: the fused triple, or the fused pair at
+    its first stage and one launch per other stage."""
+    if plan.fused3 is not None:
+        return [plan.fused3]
+    out, i = [], 0
+    while i < len(plan.stages):
+        if plan.fused is not None and i == plan.fused.first:
+            out.append(plan.fused)
+            i += 2
+        else:
+            out.append(plan.stages[i])
+            i += 1
+    return out
+
+
+def _launch_schedule(launch, cs: dict):
+    """The ESOP schedule(s) ``launch`` reads, built host-side from concrete
+    coefficients — for callers that run it where they are tracers (a
+    ``shard_map`` body, a jitted backward walk).  None for dense stages."""
+    esop = ops.esop_plan_cached
+    if isinstance(launch, FusedTriplePlan):
+        return (esop(cs[launch.mode_a], launch.bna, launch.bka),
+                esop(cs[launch.mode_b], launch.bnb, launch.kbp),
+                esop(cs[launch.mode_c], launch.bnc, launch.kcp))
+    if isinstance(launch, FusedPairPlan):
+        return (esop(cs[launch.mode_a], launch.bna, launch.bka),
+                esop(cs[launch.mode_b], launch.bnb, launch.kbp))
+    if launch.backend == "esop":
+        return esop(cs[launch.mode], launch.bk, launch.bn)
+    return None
+
+
+def _run_launches(launches, y, cs: dict, *, use_pallas, mesh=None,
+                  schedules=None):
+    """Run ``launches`` (from :func:`_launches`, or a run of stages) on
+    ``y``, yielding each launch's ``(output, info)`` as it is dispatched.
+
+    Every executor walks its stages here: the eager forward (no
+    ``schedules`` — each lowering looks its own up), the ``shard_map``
+    body (``mesh`` set: a sharded-mode stage runs its local partial
+    product + ``psum_scatter``) and the backward's staged pieces.  A
+    generator, so the eager forward holds no intermediate past the launch
+    that consumes it.
+    """
+    for j, ln in enumerate(launches):
+        sched = None if schedules is None else schedules[j]
+        if isinstance(ln, FusedTriplePlan):
+            y, info = lower_fused_triple(y, cs[ln.mode_a], cs[ln.mode_b],
+                                         cs[ln.mode_c], ln,
+                                         use_pallas=use_pallas, plans=sched)
+        elif isinstance(ln, FusedPairPlan):
+            y, info = lower_fused_pair(y, cs[ln.mode_a], cs[ln.mode_b], ln,
+                                       use_pallas=use_pallas, plans=sched)
+        elif mesh is not None and ln.axis is not None:
+            y, info = lower_sharded_stage(y, cs[ln.mode], ln, mesh,
+                                          use_pallas=use_pallas)
+        else:
+            y, info = lower_stage(y, cs[ln.mode], ln, use_pallas=use_pallas,
+                                  esop_plan=sched)
+        yield y, info
+
+
 def execute_with_info(
     plan: GemtPlan,
     x: jnp.ndarray,
@@ -402,30 +468,10 @@ def execute_with_info(
                                      "shape": tuple(x.shape),
                                      "key": plan.key})
     with sp:
-        cs = {1: c1, 2: c2, 3: c3}
-        y = x
         stage_infos = []
-        i = 0
-        while i < len(plan.stages):
-            if plan.fused3 is not None and i == 0:
-                ft = plan.fused3
-                y, finfo = lower_fused_triple(y, cs[ft.mode_a], cs[ft.mode_b],
-                                              cs[ft.mode_c], ft,
-                                              use_pallas=use_pallas)
-                stage_infos.append(finfo)
-                i += 3
-                continue
-            if plan.fused is not None and i == plan.fused.first:
-                fp = plan.fused
-                y, finfo = lower_fused_pair(y, cs[fp.mode_a], cs[fp.mode_b],
-                                            fp, use_pallas=use_pallas)
-                stage_infos.append(finfo)
-                i += 2
-                continue
-            st = plan.stages[i]
-            y, sinfo = lower_stage(y, cs[st.mode], st, use_pallas=use_pallas)
-            stage_infos.append(sinfo)
-            i += 1
+        for y, si in _run_launches(_launches(plan), x, {1: c1, 2: c2, 3: c3},
+                                   use_pallas=use_pallas):
+            stage_infos.append(si)
         if out is not None:
             y = out + y
         info = _assemble_info(plan, stage_infos)
@@ -514,6 +560,37 @@ def _assemble_info(plan: GemtPlan, stage_infos: list[dict]) -> dict:
     }
 
 
+def _spec(plan: GemtPlan, batched: bool):
+    """The stationary tensor's partition spec (the same at every stage
+    boundary, forward and adjoint: only N_s↔K_s extents change)."""
+    return P(plan.batch_axis, *plan.axes) if batched else P(*plan.axes)
+
+
+def _shard_map_program(launches, schedules, spec, mesh, use_pallas,
+                       every: bool = False):
+    """``shard_map`` ``launches`` over ``mesh`` (not jitted).  The program
+    takes ``(x, c1, c2, c3)`` — the tensor sharded per ``spec``, the
+    coefficients replicated — and returns the last launch's output, or
+    with ``every`` each launch's (the backward's stage boundaries).
+    ``schedules`` are :func:`_launch_schedule`'s, built before the body,
+    where the coefficients are tracers.  Returns ``(fn, stage_infos)``;
+    ``stage_infos`` is filled at trace time.
+    """
+    stage_infos: list[dict] = []
+
+    def body(x_l, c1_l, c2_l, c3_l):
+        ys, infos = zip(*_run_launches(
+            launches, x_l, {1: c1_l, 2: c2_l, 3: c3_l}, use_pallas=use_pallas,
+            mesh=mesh, schedules=schedules))
+        stage_infos[:] = infos  # body re-traces refill, they never duplicate
+        return ys if every else ys[-1]
+
+    out_specs = (spec,) * len(launches) if every else spec
+    fn = shard_map(body, mesh=mesh, in_specs=(spec, P(), P(), P()),
+                   out_specs=out_specs, check_vma=False)
+    return fn, stage_infos
+
+
 def _sharded_callable(plan: GemtPlan, mesh, use_pallas,
                       cs: dict[int, jnp.ndarray], batched: bool):
     """Build the jitted ``shard_map`` program executing ``plan`` on ``mesh``.
@@ -525,66 +602,10 @@ def _sharded_callable(plan: GemtPlan, mesh, use_pallas,
     ``stage_infos`` is populated at trace time (all entries are static
     host-side accounting, identical for every call of this program).
     """
-    fp = plan.fused
-    ft = plan.fused3
-    fused_idx = set() if fp is None else {fp.first, fp.first + 1}
-    if ft is not None:
-        fused_idx = {0, 1, 2}
-    esop_plans = {}
-    for i, st in enumerate(plan.stages):
-        if st.backend == "esop" and i not in fused_idx:
-            esop_plans[st.mode] = ops.esop_plan_cached(cs[st.mode], st.bk,
-                                                       st.bn)
-    fused_plans = None
-    if fp is not None:
-        fused_plans = (ops.esop_plan_cached(cs[fp.mode_a], fp.bna, fp.bka),
-                       ops.esop_plan_cached(cs[fp.mode_b], fp.bnb, fp.kbp))
-    fused3_plans = None
-    if ft is not None:
-        fused3_plans = (ops.esop_plan_cached(cs[ft.mode_a], ft.bna, ft.bka),
-                        ops.esop_plan_cached(cs[ft.mode_b], ft.bnb, ft.kbp),
-                        ops.esop_plan_cached(cs[ft.mode_c], ft.bnc, ft.kcp))
-
-    spec = (P(plan.batch_axis, *plan.axes) if batched else P(*plan.axes))
-    stage_infos: list[dict] = []
-
-    def body(x_l, c1_l, c2_l, c3_l):
-        del stage_infos[:]  # body re-traces refill, they never duplicate
-        cs_l = {1: c1_l, 2: c2_l, 3: c3_l}
-        y = x_l
-        i = 0
-        while i < len(plan.stages):
-            if ft is not None and i == 0:
-                y, finfo = lower_fused_triple(y, cs_l[ft.mode_a],
-                                              cs_l[ft.mode_b],
-                                              cs_l[ft.mode_c], ft,
-                                              use_pallas=use_pallas,
-                                              plans=fused3_plans)
-                stage_infos.append(finfo)
-                i += 3
-                continue
-            if fp is not None and i == fp.first:
-                y, finfo = lower_fused_pair(y, cs_l[fp.mode_a],
-                                            cs_l[fp.mode_b], fp,
-                                            use_pallas=use_pallas,
-                                            plans=fused_plans)
-                stage_infos.append(finfo)
-                i += 2
-                continue
-            st = plan.stages[i]
-            if st.axis is None:
-                y, sinfo = lower_stage(y, cs_l[st.mode], st,
-                                       use_pallas=use_pallas,
-                                       esop_plan=esop_plans.get(st.mode))
-            else:
-                y, sinfo = lower_sharded_stage(y, cs_l[st.mode], st, mesh,
-                                               use_pallas=use_pallas)
-            stage_infos.append(sinfo)
-            i += 1
-        return y
-
-    fn = shard_map(body, mesh=mesh, in_specs=(spec, P(), P(), P()),
-                   out_specs=spec, check_vma=False)
+    launches = _launches(plan)
+    fn, stage_infos = _shard_map_program(
+        launches, [_launch_schedule(ln, cs) for ln in launches],
+        _spec(plan, batched), mesh, use_pallas)
     return jax.jit(fn), stage_infos
 
 
@@ -792,516 +813,188 @@ def _kernels_live(use_pallas, *arrays) -> bool:
                         for a in arrays))
 
 
-def _execute_vjp_composed(plan: GemtPlan, adj: GemtPlan,
-                          chain: AdjointChainPlan, x, cs: dict, cts: dict,
-                          g, use_pallas) -> tuple:
-    """The fused walk as ONE cached jit — the span-free hot path.
+class _Piece(NamedTuple):
+    """One launch of the backward walk (:func:`_backward_pieces`)."""
 
-    Same engine-lowered pieces as :func:`_execute_vjp` (on TPU every
-    ``pallas_call`` inside the program is still its own kernel launch,
-    so ``grad_launches`` accounting is identical), but a single dispatch
-    drops the per-piece Python cost and lets XLA share subexpressions
-    across the recompute / adjoint / coefficient programs.  Runs with
-    tracing enabled take the multi-dispatch walk instead so each span
-    times a real launch — a span inside a jitted body would only fire
-    at trace time.
+    kind: str  # grad_recompute | grad_x | grad_chain | coeff_grad
+    backend: str  # fused | sr_gemm | esop | einsum
+    modes: tuple
+    stage: StagePlan | None = None  # a staged launch: the stage it runs
+    tiles: tuple | None = None  # a chain launch: the chain plan's tiles
 
-    The ``stage_infos`` are static per (plan, chain): the staged-stage
-    entries come from ``lower_stage`` at trace time, captured into a
-    cell cached next to the compiled walk.
+    @property
+    def label(self) -> str:
+        return f"{self.kind}:{self.backend}:m{''.join(map(str, self.modes))}"
+
+
+def _coeff_rows(plan: GemtPlan, batch: int, mode: int) -> int:
+    """Rows of dC_s's rank-k product: every non-s extent of the stage's
+    input (modes contracted before s at K, after it at N) times the
+    batch."""
+    rows = batch
+    for m in (1, 2, 3):
+        if m != mode:
+            before = plan.order.index(m) < plan.order.index(mode)
+            rows *= (plan.out_shape if before else plan.in_shape)[m - 1]
+    return rows
+
+
+def _backward_pieces(plan: GemtPlan, adj: GemtPlan, chain: AdjointChainPlan,
+                     g_shape, g_dtype, *, sharded: bool,
+                     traced: bool) -> tuple:
+    """The backward's launches, in order — what the walk runs, what
+    ``info["grad_*"]`` predicts and what the ``grad.*`` counters count.
+
+    Three phases (docs/engine.md, "Differentiation"):
+
+    1. *recompute* ``y1, y2`` (residuals are just ``(x, C_s)``): one chain
+       pair when ``chain.rec_fused``, else the staged prefix
+       ``plan.stages[:-1]``;
+    2. *adjoint* ``dX = g ×C₃ᵀ ×C₂ᵀ ×C₁ᵀ`` with the boundary cotangents
+       ``g1, g2``: a chain triple (depth 3), a chain pair plus one staged
+       tail (depth 2), or the staged chain over ``adj.stages``;
+    3. *coefficient cotangents* ``dC_s = unfold(y_{i-1})ᵀ @ unfold(g_i)``:
+       one batched launch (fused walk), one per mode (staged), or one
+       global einsum program (mesh — its operands are global sharded
+       arrays, where only ``dot_general`` partitions).
+
+    The fused walk needs ``chain.depth >= 2`` and neither a mesh (each
+    sharded stage needs its ``psum_scatter``) nor traced inputs (its
+    programs are built around host-side ESOP schedules).
     """
-    wkey = ("vjp_fused_walk", plan.key, adj.key, chain.depth,
-            chain.rec_fused, use_pallas, x.ndim,
-            _fingerprint(cs[1]), _fingerprint(cs[2]), _fingerprint(cs[3]))
-    hit = _SHARDED_FN_CACHE.get(wkey)
-    if hit is None:
-        m0, m1, m2 = chain.modes
-        rec_plan = adj_plan = None
-        if chain.rec_fused:
-            ma, mb = chain.rec_modes
-            if _kernels_live(use_pallas, cs[ma], cs[mb]):
-                rt = chain.rec_tiles
-                rec_plan = ops.esop_plan_cached(cs[ma], rt[3], rt[1])
-        if chain.depth == 3:
-            if _kernels_live(use_pallas, cts[m0], cts[m1], cts[m2]):
-                t3 = chain.tiles
-                adj_plan = ops.esop_plan_cached(cts[m0], t3[4], t3[1])
-        elif _kernels_live(use_pallas, cts[m0], cts[m1]):
-            t2 = chain.tiles
-            adj_plan = ops.esop_plan_cached(cts[m0], t2[3], t2[1])
-        # Staged ESOP stages inside the jit see tracer coefficients, which
-        # have no host-readable block schedule: build them here, as the
-        # sharded program does.
-        rec_esop = {st.mode: ops.esop_plan_cached(cs[st.mode], st.bk, st.bn)
-                    for st in plan.stages[:-1] if st.backend == "esop"}
-        tail = adj.stages[2]
-        tail_esop = (ops.esop_plan_cached(cts[tail.mode], tail.bk, tail.bn)
-                     if tail.backend == "esop" else None)
-        infos_cell: list = []
+    fused = chain.depth >= 2 and not (sharded or traced)
 
-        def walk_body(x_, g_, c1_, c2_, c3_, t1_, t2_, t3_):
-            csd = {1: c1_, 2: c2_, 3: c3_}
-            ctd = {1: t1_, 2: t2_, 3: t3_}
-            infos = []
-            if chain.rec_fused:
-                y2, y1 = lower_chain_pair(
-                    x_, csd[chain.rec_modes[0]], csd[chain.rec_modes[1]],
-                    chain.rec_modes[0], chain.rec_modes[1], chain.rec_tiles,
-                    use_pallas=use_pallas, plan_a=rec_plan)
-                infos.append({"kind": "grad_recompute", "backend": "fused",
-                              "modes": chain.rec_modes,
-                              "vmem_bytes": chain.rec_vmem_bytes})
-                ys = [x_, y1, y2]
-            else:
-                ys, y = [x_], x_
-                for st in plan.stages[:-1]:
-                    y, si = lower_stage(y, csd[st.mode], st,
-                                        use_pallas=use_pallas,
-                                        esop_plan=rec_esop.get(st.mode))
-                    infos.append(dict(si, kind="grad_recompute"))
-                    ys.append(y)
-            if chain.depth == 3:
-                dx, g1, g2 = lower_chain_triple(
-                    g_, ctd[m0], ctd[m1], ctd[m2], m0, m1, m2, chain.tiles,
-                    use_pallas=use_pallas, plan_a=adj_plan)
-                infos.append({"kind": "grad_x", "backend": "fused",
-                              "modes": chain.modes,
-                              "vmem_bytes": chain.vmem_bytes})
-            else:
-                g2, g1 = lower_chain_pair(
-                    g_, ctd[m0], ctd[m1], m0, m1, chain.tiles,
-                    use_pallas=use_pallas, plan_a=adj_plan)
-                infos.append({"kind": "grad_x", "backend": "fused",
-                              "modes": chain.modes[:2],
-                              "vmem_bytes": chain.vmem_bytes})
-                dx, si = lower_stage(g2, ctd[tail.mode], tail,
-                                     use_pallas=use_pallas,
-                                     esop_plan=tail_esop)
-                infos.append(dict(si, kind="grad_chain"))
-            dcl = lower_coeff_grad_batch(ys, [g2, g1, g_], plan.order,
-                                         use_pallas=use_pallas)
-            infos.append({"kind": "coeff_grad", "backend": "fused",
-                          "modes": plan.order})
-            if not infos_cell:
-                infos_cell.extend(infos)
-            return (dx,) + tuple(dcl)
+    def staged(kind, stages):
+        return [_Piece(kind, st.backend, (st.mode,), stage=st)
+                for st in stages]
 
-        hit = (jax.jit(walk_body), infos_cell)
-        _SHARDED_FN_CACHE[wkey] = hit
-    fn, infos = hit
-    out = fn(x, g, cs[1], cs[2], cs[3], cts[1], cts[2], cts[3])
-    dcs = {mode: out[1 + i] for i, mode in enumerate(plan.order)}
-    return out[0], dcs, list(infos)
-
-
-def _execute_vjp(plan: GemtPlan, adj: GemtPlan, chain: AdjointChainPlan, x,
-                 cs: dict, cts: dict, g, use_pallas) -> tuple:
-    """Single-device backward pass.  Returns ``(dx, dcs, stage_infos)``.
-
-    Three engine-lowered pieces (see docs/engine.md "Differentiation"),
-    each fused when ``chain`` (:func:`plan_adjoint_chain`) says the byte
-    model wins and the tiles fit VMEM:
-
-    1. *forward recompute* — the first two forward stages rebuild the
-       stage-boundary inputs ``y0=x, y1, y2`` (residuals are just
-       ``(x, C_s)``): one chain-pair launch when ``chain.rec_fused``,
-       else two staged launches;
-    2. *adjoint chain* — ``dX = g ×C₃ᵀ ×C₂ᵀ ×C₁ᵀ`` with the stage-boundary
-       cotangents ``g1, g2`` emitted from the same launch (depth 3: one
-       chain-triple launch; depth 2: a chain-pair launch plus one staged
-       tail stage; depth 0: the legacy staged walk);
-    3. *coefficient cotangents* — ``dC_s = unfold(y_{i-1})ᵀ @ unfold(g_i)``
-       as one batched multi-output launch (staged walk: three rank-k
-       launches).
-
-    Tracers (an outer jit differentiating through us) take the staged
-    walk: the fused programs are built host-side around precomputed ESOP
-    schedules, which a traced coefficient cannot provide.
-
-    Unless obs's tracer is enabled or a fault hook is installed, the
-    pieces run as ONE composed jit (:func:`_execute_vjp_composed`) — same
-    launches, one dispatch; the multi-dispatch walk below exists so spans
-    time real launches.  A profiler session alone keeps the composed jit,
-    so a profile shows the program that runs without it.
-    """
-    if chain.depth < 2 or _is_traced(x, g, *cs.values(), *cts.values()):
-        return _execute_vjp_staged(plan, adj, x, cs, cts, g, use_pallas)
-    if not (_trace.get_tracer().enabled
-            or _trace.get_fault_hook() is not None):
-        # hot path: the whole walk as one dispatch (identical launches)
-        return _execute_vjp_composed(plan, adj, chain, x, cs, cts, g,
-                                     use_pallas)
-
-    infos = []
-    # --- forward recompute: y1, y2 ---
-    if chain.rec_fused:
-        ma, mb = chain.rec_modes
-        rkey = ("vjp_rec_chain", plan.key, chain.rec_tiles, use_pallas,
-                x.ndim, _fingerprint(cs[ma]), _fingerprint(cs[mb]))
-        fn = _SHARDED_FN_CACHE.get(rkey)
-        if fn is None:
-            rt = chain.rec_tiles
-            plan_a = (ops.esop_plan_cached(cs[ma], rt[3], rt[1])
-                      if _kernels_live(use_pallas, cs[ma], cs[mb]) else None)
-
-            def rec_body(x_, ca, cb, _m=(ma, mb), _t=rt, _p=plan_a):
-                return lower_chain_pair(x_, ca, cb, _m[0], _m[1], _t,
-                                        use_pallas=use_pallas, plan_a=_p)
-
-            fn = jax.jit(rec_body)
-            _SHARDED_FN_CACHE[rkey] = fn
-        sp = _trace.NULL_SPAN
-        if _trace.enabled():
-            sp = _trace.span("grad.recompute:fused",
-                             {"modes": chain.rec_modes,
-                              "vmem_bytes": chain.rec_vmem_bytes})
-        with sp:
-            y2, y1 = fn(x, cs[ma], cs[mb])
-        infos.append({"kind": "grad_recompute", "backend": "fused",
-                      "modes": chain.rec_modes,
-                      "vmem_bytes": chain.rec_vmem_bytes})
-        ys = [x, y1, y2]
+    if fused and chain.rec_fused:
+        pieces = [_Piece("grad_recompute", "fused", chain.rec_modes,
+                         tiles=chain.rec_tiles)]
     else:
-        ys = [x]
-        y = x
-        for st in plan.stages[:-1]:
-            sp = _trace.NULL_SPAN
-            if _trace.enabled():
-                sp = _trace.span(f"grad.recompute:m{st.mode}",
-                                 {"mode": st.mode, "backend": st.backend,
-                                  "macs": st.macs})
-            with sp:
-                y, si = lower_stage(y, cs[st.mode], st,
-                                    use_pallas=use_pallas)
-            si["kind"] = "grad_recompute"
-            infos.append(si)
-            ys.append(y)
-
-    # --- adjoint chain: dx (+ emitted cotangents g1, g2) ---
-    m0, m1, m2 = chain.modes
-    if chain.depth == 3:
-        akey = ("vjp_adj_chain", adj.key, chain.tiles, use_pallas, g.ndim,
-                _fingerprint(cts[m0]), _fingerprint(cts[m1]),
-                _fingerprint(cts[m2]))
-        fn = _SHARDED_FN_CACHE.get(akey)
-        if fn is None:
-            t3 = chain.tiles
-            plan_a = (ops.esop_plan_cached(cts[m0], t3[4], t3[1])
-                      if _kernels_live(use_pallas, cts[m0], cts[m1],
-                                       cts[m2]) else None)
-
-            def adj_body(g_, c0, c1, c2, _m=(m0, m1, m2), _t=t3,
-                         _p=plan_a):
-                return lower_chain_triple(g_, c0, c1, c2, _m[0], _m[1],
-                                          _m[2], _t, use_pallas=use_pallas,
-                                          plan_a=_p)
-
-            fn = jax.jit(adj_body)
-            _SHARDED_FN_CACHE[akey] = fn
-        sp = _trace.NULL_SPAN
-        if _trace.enabled():
-            sp = _trace.span("grad.x:fused",
-                             {"modes": chain.modes, "depth": 3,
-                              "vmem_bytes": chain.vmem_bytes})
-        with sp:
-            dx, g1, g2 = fn(g, cts[m0], cts[m1], cts[m2])
-        infos.append({"kind": "grad_x", "backend": "fused",
-                      "modes": chain.modes, "vmem_bytes": chain.vmem_bytes})
-        gs = [g, g1, g2]
-    else:  # depth == 2: chain pair + one staged tail stage
-        akey = ("vjp_adj_chain", adj.key, chain.tiles, use_pallas, g.ndim,
-                _fingerprint(cts[m0]), _fingerprint(cts[m1]))
-        fn = _SHARDED_FN_CACHE.get(akey)
-        if fn is None:
-            t2 = chain.tiles
-            plan_a = (ops.esop_plan_cached(cts[m0], t2[3], t2[1])
-                      if _kernels_live(use_pallas, cts[m0], cts[m1])
-                      else None)
-
-            def adj_body(g_, c0, c1, _m=(m0, m1), _t=t2, _p=plan_a):
-                return lower_chain_pair(g_, c0, c1, _m[0], _m[1], _t,
-                                        use_pallas=use_pallas, plan_a=_p)
-
-            fn = jax.jit(adj_body)
-            _SHARDED_FN_CACHE[akey] = fn
-        sp = _trace.NULL_SPAN
-        if _trace.enabled():
-            sp = _trace.span("grad.x:fused",
-                             {"modes": chain.modes[:2], "depth": 2,
-                              "vmem_bytes": chain.vmem_bytes})
-        with sp:
-            g2, g1 = fn(g, cts[m0], cts[m1])
-        infos.append({"kind": "grad_x", "backend": "fused",
-                      "modes": chain.modes[:2],
-                      "vmem_bytes": chain.vmem_bytes})
-        st = adj.stages[2]
-        tkey = ("vjp_adj_tail", adj.key, use_pallas, g.ndim,
-                _fingerprint(cts[st.mode]))
-        hit = _SHARDED_FN_CACHE.get(tkey)
-        if hit is None:
-            si_cell: dict = {}
-
-            def tail_body(g2_, _c=cts[st.mode], _st=st):
-                # eager lower_stage pays pad/crop dispatch per call; the
-                # jit replays one cached program.  The stage info is
-                # static metadata — captured at trace time, reused after.
-                y_, si_ = lower_stage(g2_, _c, _st, use_pallas=use_pallas)
-                si_cell.update(si_)
-                return y_
-
-            hit = (jax.jit(tail_body), si_cell)
-            _SHARDED_FN_CACHE[tkey] = hit
-        tail_fn, tail_si = hit
-        sp = _trace.NULL_SPAN
-        if _trace.enabled():
-            sp = _trace.span(f"grad.chain:m{st.mode}",
-                             {"mode": st.mode, "backend": st.backend,
-                              "macs": st.macs})
-        with sp:
-            dx = tail_fn(g2)
-        infos.append(dict(tail_si, kind="grad_chain"))
-        gs = [g, g1, g2]
-
-    # --- coefficient cotangents: one batched multi-output launch ---
-    ckey = ("vjp_coeff_batch", plan.key, use_pallas, x.ndim)
-    fn = _SHARDED_FN_CACHE.get(ckey)
-    if fn is None:
-        order = plan.order
-
-        def coeff_body(y0, y1_, y2_, g0, g1_, g2_, _o=order):
-            # pairing as in the staged walk: dC_{order[i]} couples the
-            # stage-i input ys[i] with the matching cotangent gs[2-i]
-            return tuple(lower_coeff_grad_batch(
-                [y0, y1_, y2_], [g2_, g1_, g0], _o,
-                use_pallas=use_pallas))
-
-        fn = jax.jit(coeff_body)
-        _SHARDED_FN_CACHE[ckey] = fn
-    sp = _trace.NULL_SPAN
-    if _trace.enabled():
-        sp = _trace.span("grad.coeff:batched", {"modes": plan.order})
-    with sp:
-        dcl = fn(ys[0], ys[1], ys[2], gs[0], gs[1], gs[2])
-    infos.append({"kind": "coeff_grad", "backend": "fused",
-                  "modes": plan.order})
-    dcs = {mode: dcl[i] for i, mode in enumerate(plan.order)}
-    return dx, dcs, infos
+        pieces = staged("grad_recompute", plan.stages[:-1])
+    if not fused:
+        pieces += staged("grad_x", adj.stages)
+    elif chain.depth == 3:
+        pieces.append(_Piece("grad_x", "fused", chain.modes,
+                             tiles=chain.tiles))
+    else:
+        pieces.append(_Piece("grad_x", "fused", chain.modes[:2],
+                             tiles=chain.tiles))
+        pieces += staged("grad_chain", adj.stages[2:])
+    if fused:
+        pieces.append(_Piece("coeff_grad", "fused", plan.order))
+    elif sharded:
+        pieces.append(_Piece("coeff_grad", "einsum", plan.order))
+    else:
+        batch = int(g_shape[0]) if len(g_shape) == 4 else 1
+        pieces += [_Piece("coeff_grad", coeff_grad_backend(
+            _coeff_rows(plan, batch, m), plan.in_shape[m - 1],
+            plan.out_shape[m - 1], g_dtype), (m,)) for m in plan.order]
+    return tuple(pieces)
 
 
-def _execute_vjp_staged(plan: GemtPlan, adj: GemtPlan, x, cs: dict,
-                        cts: dict, g, use_pallas) -> tuple:
-    """The legacy eight-launch staged backward walk (``fuse=False``, traced
-    inputs, or a declined chain plan).  Returns ``(dx, dcs, stage_infos)``.
+def _piece_schedule(p: _Piece, cs: dict, cts: dict, use_pallas):
+    """The ESOP schedule a piece's launch reads, built host-side (inside
+    the walk's program its coefficients are tracers)."""
+    coeffs = cs if p.kind == "grad_recompute" else cts
+    if p.stage is not None:
+        return _launch_schedule(p.stage, coeffs)
+    if p.tiles is not None and _kernels_live(
+            use_pallas, *(coeffs[m] for m in p.modes)):
+        bna = p.tiles[3 if len(p.modes) == 2 else 4]
+        return ops.esop_plan_cached(coeffs[p.modes[0]], bna, p.tiles[1])
+    return None
+
+
+def _walk(pieces: tuple, schedules: tuple, x, g, cs: dict, cts: dict, *,
+          order, mesh, spec, use_pallas) -> tuple:
+    """Run the backward's pieces.  Returns ``(dx, dcs)``.
+
+    ``ys`` collects the forward stage inputs ``[x, y1, y2]`` and ``gs``
+    the cotangent chain ``[g, g1, g2, dX]``; a chain launch emits its
+    intermediates, and a run of staged pieces goes through the forward's
+    launch loop — inside a ``shard_map`` program under a mesh.
     """
-    infos = []
-    ys = [x]
-    y = x
-    for st in plan.stages[:-1]:
-        sp = _trace.NULL_SPAN
-        if _trace.enabled():
-            sp = _trace.span(f"grad.recompute:m{st.mode}",
-                             {"mode": st.mode, "backend": st.backend,
-                              "macs": st.macs})
-        with sp:
-            y, si = lower_stage(y, cs[st.mode], st, use_pallas=use_pallas)
-        si["kind"] = "grad_recompute"
-        infos.append(si)
-        ys.append(y)
-
-    gs = [g]
-    gi = g
-    for st in adj.stages:
-        sp = _trace.NULL_SPAN
-        if _trace.enabled():
-            sp = _trace.span(f"grad.x:m{st.mode}",
-                             {"mode": st.mode, "backend": st.backend,
-                              "macs": st.macs})
-        with sp:
-            gi, si = lower_stage(gi, cts[st.mode], st,
-                                 use_pallas=use_pallas)
-        si["kind"] = "grad_x"
-        infos.append(si)
-        gs.append(gi)
-    dx = gs.pop()  # gs keeps [g, g1, g2]
-
-    dcs = {}
-    for i, mode in enumerate(plan.order):
-        sp = _trace.NULL_SPAN
-        if _trace.enabled():
-            sp = _trace.span(f"grad.coeff:m{mode}", {"mode": mode})
-        with sp:
-            dc, ci = lower_coeff_grad(ys[i], gs[2 - i], mode,
-                                      use_pallas=use_pallas)
-        infos.append(ci)
-        dcs[mode] = dc
-    return dx, dcs, infos
-
-
-def _sharded_prefix_callable(plan: GemtPlan, mesh, use_pallas,
-                             cs: dict[int, jnp.ndarray], batched: bool):
-    """Jitted shard_map recomputing the first two forward stage boundaries.
-
-    The backward pass needs the stage-input tensors ``y1, y2`` globally;
-    each stage runs exactly as in the forward program (kernels on local
-    shards, ``psum_scatter`` on sharded modes), and every boundary keeps
-    the stationary spec — the per-mode axis assignment never changes, only
-    N_s↔K_s extents do.
-    """
-    esop_plans = {}
-    for st in plan.stages[:-1]:
-        if st.backend == "esop":
-            esop_plans[st.mode] = ops.esop_plan_cached(cs[st.mode], st.bk,
-                                                       st.bn)
-    spec = (P(plan.batch_axis, *plan.axes) if batched else P(*plan.axes))
-    stage_infos: list[dict] = []
-
-    def body(x_l, c1_l, c2_l, c3_l):
-        del stage_infos[:]
-        cs_l = {1: c1_l, 2: c2_l, 3: c3_l}
-        y = x_l
-        inter = []
-        for st in plan.stages[:-1]:
-            if st.axis is None:
-                y, si = lower_stage(y, cs_l[st.mode], st,
-                                    use_pallas=use_pallas,
-                                    esop_plan=esop_plans.get(st.mode))
+    ys, gs, dcs = [x], [g], {}
+    i = 0
+    while i < len(pieces):
+        p = pieces[i]
+        if p.kind == "coeff_grad":
+            if p.backend == "fused":
+                dcs.update(zip(order, lower_coeff_grad_batch(
+                    ys[:3], gs[2::-1], order, use_pallas=use_pallas)))
             else:
-                y, si = lower_sharded_stage(y, cs_l[st.mode], st, mesh,
-                                            use_pallas=use_pallas)
-            stage_infos.append(si)
-            inter.append(y)
-        return tuple(inter)
-
-    fn = shard_map(body, mesh=mesh, in_specs=(spec, P(), P(), P()),
-                   out_specs=(spec, spec), check_vma=False)
-    return jax.jit(fn), stage_infos
-
-
-def _sharded_chain_callable(adj: GemtPlan, mesh, use_pallas,
-                            cts: dict[int, jnp.ndarray], batched: bool):
-    """Jitted shard_map running the full adjoint chain staged, returning
-    ``(g1, g2, dx)``.
-
-    The chain runs staged even when the adjoint plan could fuse (only
-    possible in the all-modes-local corner): the intermediates *are* the
-    coefficient cotangents' operands, and a sharded-mode stage's
-    ``psum_scatter`` must fire between them — the X-cotangent's collective
-    handling is exactly the forward schedule's, inherited through
-    ``lower_sharded_stage``.
-    """
-    esop_plans = {}
-    for st in adj.stages:
-        if st.backend == "esop":
-            esop_plans[st.mode] = ops.esop_plan_cached(cts[st.mode], st.bk,
-                                                       st.bn)
-    spec = (P(adj.batch_axis, *adj.axes) if batched else P(*adj.axes))
-    stage_infos: list[dict] = []
-
-    def body(g_l, c1t_l, c2t_l, c3t_l):
-        del stage_infos[:]
-        ct_l = {1: c1t_l, 2: c2t_l, 3: c3t_l}
-        y = g_l
-        inter = []
-        for st in adj.stages:
-            if st.axis is None:
-                y, si = lower_stage(y, ct_l[st.mode], st,
-                                    use_pallas=use_pallas,
-                                    esop_plan=esop_plans.get(st.mode))
+                for m in p.modes:
+                    j = order.index(m)
+                    dcs[m] = lower_coeff_grad(ys[j], gs[2 - j], m,
+                                              use_pallas=use_pallas,
+                                              backend=p.backend)[0]
+            i += 1
+            continue
+        out, coeffs = (ys, cs) if p.kind == "grad_recompute" else (gs, cts)
+        if p.stage is None:  # one chain launch: (y, y1[, y2])
+            m = p.modes
+            if len(m) == 3:
+                res = lower_chain_triple(
+                    out[-1], coeffs[m[0]], coeffs[m[1]], coeffs[m[2]], *m,
+                    p.tiles, use_pallas=use_pallas, plan_a=schedules[i])
             else:
-                y, si = lower_sharded_stage(y, ct_l[st.mode], st, mesh,
-                                            use_pallas=use_pallas)
-            si = dict(si)
-            si["kind"] = "grad_x"
-            stage_infos.append(si)
-            inter.append(y)
-        return tuple(inter)
-
-    fn = shard_map(body, mesh=mesh, in_specs=(spec, P(), P(), P()),
-                   out_specs=(spec, spec, spec), check_vma=False)
-    return jax.jit(fn), stage_infos
-
-
-def _plan_tiles(plan: GemtPlan) -> tuple:
-    return tuple((s.bm, s.bn, s.bk) for s in plan.stages)
-
-
-def _execute_vjp_sharded(plan: GemtPlan, adj: GemtPlan, mesh, x, cs: dict,
-                         cts: dict, g, use_pallas) -> tuple:
-    """Mesh backward pass: chain + recompute inside ``shard_map`` programs
-    (cached like the forward program), coefficient cotangents on the
-    resulting global arrays.  Returns ``(dx, dcs, stage_infos)``."""
-    batched = x.ndim == 4
-    pkey = ("vjp_prefix", plan.key, _plan_tiles(plan), use_pallas, x.ndim,
-            _fingerprint(cs[1]), _fingerprint(cs[2]), _fingerprint(cs[3]))
-    hit = _SHARDED_FN_CACHE.get(pkey)
-    if hit is None:
-        fn, infos = _sharded_prefix_callable(plan, mesh, use_pallas, cs,
-                                             batched)
-        hit = [fn, infos, None]
-        _SHARDED_FN_CACHE[pkey] = hit
-    y1, y2 = hit[0](x, cs[1], cs[2], cs[3])
-    prefix_infos = [dict(si, kind="grad_recompute") for si in hit[1]]
-
-    ckey = ("vjp_chain", adj.key, _plan_tiles(adj), use_pallas, g.ndim,
-            _fingerprint(cts[1]), _fingerprint(cts[2]), _fingerprint(cts[3]))
-    hit = _SHARDED_FN_CACHE.get(ckey)
-    if hit is None:
-        fn, infos = _sharded_chain_callable(adj, mesh, use_pallas, cts,
-                                            batched)
-        hit = [fn, infos, None]
-        _SHARDED_FN_CACHE[ckey] = hit
-    g1, g2, dx = hit[0](g, cts[1], cts[2], cts[3])
-    infos = prefix_infos + [dict(si) for si in hit[1]]
-
-    # Global-level rank-k updates: the chain/recompute arrays are global
-    # (sharded) outputs, so the contraction over their rows is complete —
-    # the cross-device sum GSPMD inserts here is the coefficient
-    # cotangent's psum (coefficients are replicated, their cotangents must
-    # be too).  Backend pinned to einsum: these operands live *outside*
-    # shard_map, where only dot_general is partitionable — a pallas_call
-    # on sharded global arrays has no SPMD rule.  All three run inside one
-    # cached jitted program (one dispatch; GSPMD partitions each einsum).
-    okey = ("vjp_coeff", plan.key, use_pallas, x.ndim)
-    cfn = _SHARDED_FN_CACHE.get(okey)
-    if cfn is None:
-        order = plan.order
-
-        def coeff_body(y0, y1_, y2_, g0, g1_, g2_, _o=order):
-            ys_l = (y0, y1_, y2_)
-            gs_l = (g0, g1_, g2_)
-            return tuple(lower_coeff_grad(ys_l[i], gs_l[2 - i], mode,
-                                          use_pallas=use_pallas,
-                                          backend="einsum")[0]
-                         for i, mode in enumerate(_o))
-
-        cfn = jax.jit(coeff_body)
-        _SHARDED_FN_CACHE[okey] = cfn
-    dcl = cfn(x, y1, y2, g, g1, g2)
-    infos.append({"kind": "coeff_grad", "backend": "einsum",
-                  "modes": plan.order, "batched": True})
-    dcs = {mode: dcl[i] for i, mode in enumerate(plan.order)}
-    return dx, dcs, infos
+                res = lower_chain_pair(
+                    out[-1], coeffs[m[0]], coeffs[m[1]], *m, p.tiles,
+                    use_pallas=use_pallas, plan_a=schedules[i])
+            out += [*res[1:], res[0]]
+            i += 1
+            continue
+        j = i
+        while (j < len(pieces) and pieces[j].stage is not None
+               and pieces[j].kind == p.kind):
+            j += 1
+        stages = [q.stage for q in pieces[i:j]]
+        if mesh is None:
+            out += [y for y, _ in _run_launches(
+                stages, out[-1], coeffs, use_pallas=use_pallas,
+                schedules=schedules[i:j])]
+        else:
+            fn, _ = _shard_map_program(stages, schedules[i:j], spec, mesh,
+                                       use_pallas, every=True)
+            out += fn(out[-1], coeffs[1], coeffs[2], coeffs[3])
+        i = j
+    return gs[3], dcs
 
 
-def _count_grad_dispatch(infos: list[dict]) -> dict:
+def _count_grad_dispatch(pieces) -> dict:
     counts = {"kernel_stages": 0, "einsum_stages": 0, "coeff_kernel": 0,
               "coeff_einsum": 0, "fused_launches": 0}
-    for si in infos:
-        kernel = si.get("backend") != "einsum"
-        if si.get("kind") == "coeff_grad":
+    for p in pieces:
+        kernel = p.backend != "einsum"
+        if p.kind == "coeff_grad":
             counts["coeff_kernel" if kernel else "coeff_einsum"] += 1
             continue
-        if si.get("backend") == "fused":
+        if p.backend == "fused":
             counts["fused_launches"] += 1
         counts["kernel_stages" if kernel else "einsum_stages"] += 1
     return counts
+
+
+def _is_sharded(plan: GemtPlan) -> bool:
+    return (any(a is not None for a in plan.axes)
+            or plan.batch_axis is not None)
 
 
 def _vjp_backward(plan: GemtPlan, mesh, x, c1, c2, c3, g, *, use_pallas,
                   esop_threshold, block_sizes, fuse, vmem_budget,
                   autotune, autotune_cache):
     """The custom-VJP backward: re-enters the engine and returns the four
-    cotangents ``(dx, dc1, dc2, dc3)``."""
+    cotangents ``(dx, dc1, dc2, dc3)``.
+
+    Runs :func:`_backward_pieces` through :func:`_walk`: with concrete
+    inputs as one cached jitted program (on TPU every ``pallas_call``
+    inside it is still its own launch), under an outer trace inline.
+    Which pieces run depends on the plans and the inputs only — never on
+    whether a tracer, fault hook or profiler is watching; the
+    ``vjp.backward`` span lists them (``pieces``).
+    """
     sp = _trace.NULL_SPAN
     if _trace.enabled():
         sp = _trace.span("vjp.backward",
@@ -1322,17 +1015,27 @@ def _vjp_backward(plan: GemtPlan, mesh, x, c1, c2, c3, g, *, use_pallas,
                      // max(adj.batch_shards, 1))
             adj = _tuned_plan(adj, cts, batch, autotune_cache, use_pallas,
                               vmem_budget, g.dtype)
-        sharded = mesh is not None and (
-            any(a is not None for a in plan.axes)
-            or plan.batch_axis is not None)
-        if sharded:
-            dx, dcs, infos = _execute_vjp_sharded(plan, adj, mesh, x, cs,
-                                                  cts, g, use_pallas)
-        else:
-            dx, dcs, infos = _execute_vjp(plan, adj, chain, x, cs, cts, g,
-                                          use_pallas)
+        sharded = mesh is not None and _is_sharded(plan)
+        traced = _is_traced(x, g, c1, c2, c3)
+        pieces = _backward_pieces(plan, adj, chain, g.shape, g.dtype,
+                                  sharded=sharded, traced=traced)
+        if sp:
+            sp.set(pieces=[p.label for p in pieces])
+        key = ("vjp_walk", plan.key, pieces, use_pallas, x.ndim,
+               _fingerprint(c1), _fingerprint(c2), _fingerprint(c3))
+        fn = None if traced else _SHARDED_FN_CACHE.get(key)
+        if fn is None:
+            fn = functools.partial(
+                _walk, pieces,
+                tuple(_piece_schedule(p, cs, cts, use_pallas)
+                      for p in pieces),
+                order=plan.order, mesh=mesh if sharded else None,
+                spec=_spec(plan, x.ndim == 4), use_pallas=use_pallas)
+            if not traced:
+                fn = _SHARDED_FN_CACHE[key] = jax.jit(fn)
+        dx, dcs = fn(x, g, cs, cts)
         _metrics.inc("grad.backward_calls")
-        for k, v in _count_grad_dispatch(infos).items():
+        for k, v in _count_grad_dispatch(pieces).items():
             _metrics.inc("grad." + k, v)
         return (_match_cotangent(dx, x),
                 _match_cotangent(dcs[1], c1),
@@ -1347,83 +1050,39 @@ def _grad_info_fields(plan: GemtPlan, adj: GemtPlan,
     Derived from the (cached) adjoint + chain plans, so ``info`` can prove
     — before any gradient is pulled — that the backward lowers through the
     engine (nonzero kernel counters, no silent einsum fallback on
-    kernel-capable shapes).  The stage counters are computed by building
-    the *predicted* ``stage_infos`` list and feeding it through the same
-    :func:`_count_grad_dispatch` the backward uses — one eager backward
-    call moves the ``grad.*`` counters by exactly these amounts.
-    ``grad_stats()`` counts actual backward executions.  (A backward
-    pulled under an outer jit takes the staged walk instead — see
-    ``_execute_vjp``.)
+    kernel-capable shapes).  The counts come from the same
+    :func:`_backward_pieces` list the backward runs and counts, so one
+    backward call with concrete inputs moves the ``grad.*`` counters by
+    exactly these amounts.  (A backward pulled under an outer jit runs the
+    staged pieces instead.)
     """
-    from .lower import coeff_grad_backend
-
+    pieces = _backward_pieces(plan, adj, chain, g_shape, g_dtype,
+                              sharded=_is_sharded(plan), traced=False)
+    fused_walk = pieces[-1].backend == "fused"
+    coeff = {m: p.backend for p in pieces if p.kind == "coeff_grad"
+             for m in p.modes}
     batch = int(g_shape[0]) if len(g_shape) == 4 else 1
-    dims = dict(zip((1, 2, 3), plan.in_shape))
-    out_dims = dict(zip((1, 2, 3), plan.out_shape))
-    sharded = (any(a is not None for a in plan.axes)
-               or plan.batch_axis is not None)
-    fused_walk = chain.depth >= 2 and not sharded
-
-    coeff_backends = []
-    coeff_macs = 0
-    for mode in (1, 2, 3):
-        # dC_s rows: every non-s forward output extent (the boundary pair
-        # shares them) times the batch; extents (N_s, K_s).
-        rows = batch
-        for m in (1, 2, 3):
-            if m != mode:
-                rows *= out_dims[m] if plan.order.index(m) < plan.order.index(mode) else dims[m]
-        # Sharded plans pin the coefficient cotangent to einsum (global
-        # arrays outside shard_map — see _execute_vjp_sharded); the fused
-        # walk batches all three into one multi-output launch.
-        coeff_backends.append(
-            "fused" if fused_walk else "einsum" if sharded else
-            coeff_grad_backend(rows, dims[mode], out_dims[mode], g_dtype))
-        coeff_macs += rows * dims[mode] * out_dims[mode]
-
-    predicted = []  # mirrors the backward's stage_infos, entry for entry
-    if fused_walk:
-        if chain.rec_fused:
-            predicted.append({"kind": "grad_recompute", "backend": "fused"})
-        else:
-            predicted += [{"kind": "grad_recompute", "backend": st.backend}
-                          for st in plan.stages[:2]]
-        predicted.append({"kind": "grad_x", "backend": "fused"})
-        if chain.depth == 3:
-            executed = (f"fused{chain.modes}",)
-        else:
-            executed = (f"fused{chain.modes[:2]}", adj.stages[2].backend)
-            predicted.append({"kind": "grad_chain",
-                              "backend": adj.stages[2].backend})
-        predicted.append({"kind": "coeff_grad", "backend": "fused"})
-    else:
-        executed = adj.backends
-        predicted += [{"kind": "grad_recompute", "backend": st.backend}
-                      for st in plan.stages[:2]]
-        predicted += [{"kind": "grad_x", "backend": st.backend}
-                      for st in adj.stages]
-        if sharded:
-            predicted.append({"kind": "coeff_grad", "backend": "einsum"})
-        else:
-            predicted += [{"kind": "coeff_grad", "backend": b}
-                          for b in coeff_backends]
-    counts = _count_grad_dispatch(predicted)
+    counts = _count_grad_dispatch(pieces)
     return {
         "grad_order": adj.order,
         "grad_backends": adj.backends,
-        "grad_backends_executed": executed,
-        "grad_coeff_backends": tuple(coeff_backends),
+        "grad_backends_executed": tuple(
+            "fused" + str(p.modes) if p.backend == "fused" else p.backend
+            for p in pieces if p.kind in ("grad_x", "grad_chain")),
+        "grad_coeff_backends": tuple(coeff[m] for m in (1, 2, 3)),
         "grad_kernel_stages": counts["kernel_stages"],
         "grad_einsum_stages": counts["einsum_stages"],
         "grad_coeff_kernel": counts["coeff_kernel"],
         "grad_coeff_einsum": counts["coeff_einsum"],
         "grad_fused_launches": counts["fused_launches"],
-        "grad_launches": chain.launches if fused_walk else len(predicted),
+        "grad_launches": len(pieces),
         "grad_chain_depth": chain.depth if fused_walk else 0,
         "grad_rec_fused": fused_walk and chain.rec_fused,
         "grad_fused": fused_walk,
         "grad_events": list(chain.events),
-        "grad_macs": adj.macs + coeff_macs,
+        "grad_macs": adj.macs + sum(
+            _coeff_rows(plan, batch, m) * plan.in_shape[m - 1]
+            * plan.out_shape[m - 1] for m in (1, 2, 3)),
         "grad_hbm_bytes_moved": (chain.hbm_bytes_fused if fused_walk
                                  else adj.hbm_bytes_staged),
         "grad_collective_bytes": adj.collective_bytes,

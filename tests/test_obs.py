@@ -501,8 +501,8 @@ def test_counter_parity_backward():
 
 
 # ---------------------------------------------------------------------------
-# acceptance: traced forward+backward exports a Chrome trace whose span
-# tree attributes all 8 backward launches by name
+# acceptance: traced forward+backward exports a Chrome trace whose
+# vjp.backward span lists all 8 backward launches by name
 # ---------------------------------------------------------------------------
 
 
@@ -512,7 +512,7 @@ def test_traced_backward_exports_eight_attributed_launches(tmp_path):
     path = str(tmp_path / "bwd_trace.json")
     with obs.session() as s:
         # fuse=False pins the adjoint to the staged chain: exactly
-        # 2 recompute + 3 grad.x + 3 grad.coeff = 8 attributed launches
+        # 2 recompute + 3 grad_x + 3 coeff_grad = 8 attributed launches
         loss = lambda *a: jnp.sum(jnp.abs(
             gemt3_planned(*a, differentiable=True, fuse=False)))
         jax.grad(loss, argnums=(0, 1, 2, 3))(x, c1, c2, c3)
@@ -520,32 +520,29 @@ def test_traced_backward_exports_eight_attributed_launches(tmp_path):
     loaded = json.loads(open(path).read())
     assert loaded == json.loads(json.dumps(doc))
     events = loaded["traceEvents"]
-    bwd = [e for e in events if e["name"].startswith("grad.")]
-    assert len(bwd) == 8, [e["name"] for e in bwd]
-    names = sorted(e["name"] for e in bwd)
-    assert sum(1 for n in names if n.startswith("grad.recompute:m")) == 2
-    assert sum(1 for n in names if n.startswith("grad.x:")) == 3
-    assert sum(1 for n in names if n.startswith("grad.coeff:m")) == 3
-    # every backward launch nests under the vjp.backward parent
-    vjp = [e for e in events if e["name"] == "vjp.backward"]
-    assert len(vjp) == 1
-    vjp_id = vjp[0]["args"]["span_id"]
-    for e in bwd:
-        assert e["args"]["parent_id"] == vjp_id
-    # each grad.* wrapper contains its lowered kernel/einsum stage span
+    (vjp,) = [e for e in events if e["name"] == "vjp.backward"]
+    pieces = vjp["args"]["pieces"]
+    assert len(pieces) == 8, pieces
+    kinds = [p.split(":")[0] for p in pieces]
+    assert kinds == ["grad_recompute"] * 2 + ["grad_x"] * 3 + [
+        "coeff_grad"] * 3
+    # the per-launch grad.* spans are retired: the pieces are the record
+    assert not [e for e in events if e["name"].startswith("grad.")]
+    # the backward's lowered stages nest under the vjp.backward span
+    vjp_id = vjp["args"]["span_id"]
     stage_like = [e for e in events
-                  if e["name"].startswith(("stage:", "coeff_grad:",
-                                           "fused_pair:", "fused_triple:"))]
-    bwd_ids = {e["args"]["span_id"] for e in bwd}
-    assert sum(1 for e in stage_like
-               if e["args"]["parent_id"] in bwd_ids) >= 8
+                  if e["name"].startswith(("stage:", "coeff_grad:"))
+                  and e["args"]["parent_id"] == vjp_id]
+    assert len(stage_like) == 8
     assert loaded["counters"]["grad.backward_calls"] == 1
+    assert loaded["counters"]["grad.kernel_stages"] + loaded["counters"][
+        "grad.einsum_stages"] == 5
 
 
 def test_fused_backward_spans_attributed_like_forward():
-    """The fused-adjoint walk's launches carry the same span-attribution
-    contract as the staged one: every grad.* wrapper nests under
-    vjp.backward and the span count equals the planned launch count."""
+    """The fused-adjoint walk's launches carry the same attribution
+    contract as the staged one: the ``vjp.backward`` span lists one piece
+    per planned launch, ``info["grad_launches"]`` of them."""
     x, c1, c2, c3 = _problem(16)
     clear_plan_cache()
     with obs.session() as s:
@@ -556,52 +553,55 @@ def test_fused_backward_spans_attributed_like_forward():
             gemt3_planned(*a, differentiable=True)))
         jax.grad(loss, argnums=(0, 1, 2, 3))(x, c1, c2, c3)
         spans = s.tracer.spans()
-    bwd = [sp for sp in spans if sp.name.startswith("grad.")]
-    assert len(bwd) == info["grad_launches"]
-    names = sorted(sp.name for sp in bwd)
-    assert "grad.recompute:fused" in names
-    assert "grad.x:fused" in names
-    assert "grad.coeff:batched" in names
-    if info["grad_chain_depth"] == 2:  # staged tail stage of the pair walk
-        assert sum(1 for n in names if n.startswith("grad.chain:m")) == 1
     (vjp,) = [sp for sp in spans if sp.name == "vjp.backward"]
-    for sp in bwd:
-        assert sp.parent_id == vjp.span_id
+    pieces = vjp.attrs["pieces"]
+    assert len(pieces) == info["grad_launches"]
+    assert pieces[0].startswith("grad_recompute:")
+    assert sum(1 for p in pieces if p.startswith("grad_x:fused:")) == 1
+    assert pieces[-1].startswith("coeff_grad:fused:")
+    tails = [p for p in pieces if p.startswith("grad_chain:")]
+    assert len(tails) == (1 if info["grad_chain_depth"] == 2 else 0)
+    assert not [sp for sp in spans if sp.name.startswith("grad.")]
 
 
-def test_profiler_session_keeps_the_composed_backward(tmp_path):
-    """A recording profiler session changes no code path: the custom-VJP
-    backward is the same one composed dispatch, with the same launch
-    counts, as with tracing off; it records ``vjp.backward`` and no
-    per-launch ``grad.*`` span."""
+@pytest.mark.parametrize("watcher", ["tracer", "fault_hook", "profiler"])
+def test_profiler_session_keeps_the_composed_backward(tmp_path, watcher):
+    """Nothing that watches selects the backward: with obs's tracer on, a
+    fault injector installed (no spec matches) or a profiler session
+    recording, the custom-VJP backward builds the same cached programs
+    and moves the same counters as with everything off."""
     from repro.engine import executor
+    from repro.runtime.faults import FaultInjector, FaultSpec
 
     x, c1, c2, c3 = _problem(16)
     loss = lambda *a: jnp.sum(jnp.abs(
         gemt3_planned(*a, differentiable=True)))
 
-    def backward(profile):
+    def backward(watch):
         clear_plan_cache()
-        with obs.session(enable_tracing=False) as s:
-            if profile:
+        with obs.session(enable_tracing=watch == "tracer") as s:
+            hook = (FaultInjector(FaultSpec("no-such-span")).install()
+                    if watch == "fault_hook" else None)
+            if watch == "profiler":
                 jax.profiler.start_trace(str(tmp_path))
             try:
                 jax.block_until_ready(
                     jax.grad(loss, argnums=(0, 1, 2, 3))(x, c1, c2, c3))
             finally:
-                if profile:
+                if watch == "profiler":
                     jax.profiler.stop_trace()
-            fns = {k[0] for k in executor._SHARDED_FN_CACHE
-                   if isinstance(k, tuple)}
-            return grad_stats(), fns, [sp.name for sp in s.tracer.spans()]
+                if hook is not None:
+                    hook.uninstall()
+            keys = set(executor._SHARDED_FN_CACHE)
+            return grad_stats(), keys, [sp.name for sp in s.tracer.spans()]
 
-    stats, fns, names = backward(profile=False)
-    p_stats, p_fns, p_names = backward(profile=True)
-    assert p_stats == stats and stats["backward_calls"] == 1
-    assert p_fns == fns and "vjp_fused_walk" in fns
+    stats, keys, names = backward(None)
+    w_stats, w_keys, w_names = backward(watcher)
+    assert w_stats == stats and stats["backward_calls"] == 1
+    assert w_keys == keys and {k[0] for k in keys} == {"vjp_walk"}
     assert names == []
-    assert "vjp.backward" in p_names
-    assert not [n for n in p_names if n.startswith("grad.")]
+    if watcher != "fault_hook":
+        assert "vjp.backward" in w_names
 
 
 # ---------------------------------------------------------------------------
