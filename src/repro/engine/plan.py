@@ -103,6 +103,14 @@ MIN_KERNEL_DIM = 8  # below this, padding overhead beats the kernels
 # the TPU compiler enforces by default, the rest left for the internal
 # scratch the model does not count.
 DEFAULT_VMEM_BUDGET = 12 * 1024 * 1024
+# The fused pair's tile search (fused_tile_sizes) prices a tiling at its
+# modeled HBM bytes over the chip's bandwidth plus a fixed cost per grid
+# step.  Bandwidth: v5e's published HBM figure, 819 GB/s.  Step cost: a
+# staged SR-GEMM step at 128x128x128 tiles measured 0.43-0.54 us on a v5e,
+# about 0.16 us of it HBM time (PERF.md, the staged row tile's finding),
+# which leaves ~0.27 us of fixed overhead a step.
+HBM_BYTES_PER_S = 819e9
+GRID_STEP_S = 0.27e-6
 # Per-shard stages below this many (batched) MACs run the einsum fallback:
 # at these sizes the kernel launch + unfold padding overhead beats any
 # streaming win (BENCH_distributed_engine D3_dense_32 measured the kernel
@@ -696,6 +704,15 @@ def fused_vmem_bytes(bu: int, bka: int, bnb: int, bna: int, kbp: int,
             + 2 * _vmem((bu, bka, kbp), out_isz))  # output tile
 
 
+def _lane_tiles(d: int) -> list[int]:
+    """Every lane tile over extent ``d``: the lane tile of an extent below
+    a lane, else each multiple of 128 that divides ``d`` padded to 128."""
+    if d <= LANE:
+        return [_lane_tile(d)]
+    lanes = _pad_up(d, LANE) // LANE
+    return [LANE * m for m in range(1, lanes + 1) if lanes % m == 0]
+
+
 def fused_tile_sizes(
     rows_total: int, na: int, ka: int, nb: int, kb: int,
     itemsize: int, vmem_budget: int = DEFAULT_VMEM_BUDGET,
@@ -704,28 +721,59 @@ def fused_tile_sizes(
 ) -> tuple[int, int, int, int, int] | None:
     """Pick ``(bu, bka, bnb, bna, kbp)`` fitting the VMEM budget, or None.
 
-    ``start`` optionally seeds ``(bka, bna, bnb)`` (the planner aligns them
-    with the staged stages' ESOP block grids so sparse skipping composes);
-    a lane seed that breaks :func:`lane_tile_ok` falls back to the default.
-    Kb is not blocked (the accumulator holds the full padded slab width so
-    stage b never revisits a partial), which is what bounds fusability:
-    when no power-of-two shrink of ``bu``/``bnb`` fits, the pair must run
-    staged.
+    A dense pair searches the legal tilings whose :func:`fused_vmem_bytes`
+    fits ``vmem_budget`` and keeps the one of least modeled time: its
+    :func:`_fused_hbm_bytes` over ``HBM_BYTES_PER_S`` plus its grid steps
+    times ``GRID_STEP_S``.  ``bu`` is a power of two up to the rows,
+    ``bnb`` a power of two from 8 up to the one covering Nb (one slab,
+    ``t_b`` 1), ``bka`` any lane tile of Ka (:func:`_lane_tiles`) and
+    ``bna`` Na's lane tile.
+
+    ``start`` seeds ``(bka, bna, bnb)`` from a block-sparse stage's ESOP
+    grid (the planner passes None for a dense stream).  A seeded pair
+    keeps that grid, so fused skipping composes with the planner's masks:
+    the lane tiles stay the seeds (one that breaks :func:`lane_tile_ok`
+    falls back to the default), and the ladder halves ``bu``/``bnb`` from
+    ``bnb`` at most 32 until the footprint fits.  Kb is not blocked (the
+    accumulator holds the full padded slab width so stage b never
+    revisits a partial), which is what bounds fusability: when no tiling
+    fits, the pair must run staged.
     """
     kbp = kb_padded(kb)
     bka0, bna0, bnb0 = start if start is not None else (None, None, None)
-    tiles = _fit_vmem({
-        "bu": _pow2_clamp(rows_total),
-        "bka": _lane_tile(ka, bka0),
-        # bnb only sizes the on-chip partial (total traffic is bnb-
-        # independent), so it starts small
-        "bnb": min(bnb0 or 32, _pow2_ceil_clamp(nb, hi=32)),
-        "bna": _lane_tile(na, bna0),
-    }, lambda t: fused_vmem_bytes(t["bu"], t["bka"], t["bnb"], t["bna"], kbp,
-                                  itemsize, accum), vmem_budget)
-    if tiles is None:
-        return None
-    return tiles["bu"], tiles["bka"], tiles["bnb"], tiles["bna"], kbp
+    bna = _lane_tile(na, bna0)
+    if any(s is not None for s in (bka0, bna0, bnb0)):
+        tiles = _fit_vmem({
+            "bu": _pow2_clamp(rows_total),
+            "bka": _lane_tile(ka, bka0),
+            "bnb": min(bnb0 or 32, _pow2_ceil_clamp(nb, hi=32)),
+            "bna": bna,
+        }, lambda t: fused_vmem_bytes(t["bu"], t["bka"], t["bnb"], t["bna"],
+                                      kbp, itemsize, accum), vmem_budget)
+        if tiles is None:
+            return None
+        return tiles["bu"], tiles["bka"], tiles["bnb"], tiles["bna"], kbp
+    bnbs = [8]
+    while bnbs[-1] < nb:
+        bnbs.append(2 * bnbs[-1])
+    t_a = _pad_up(na, bna) // bna
+    best = None
+    for bka in _lane_tiles(ka):
+        n_j = _pad_up(ka, bka) // bka
+        for bnb in bnbs:
+            t_b = _pad_up(nb, bnb) // bnb
+            for bu in (1 << s for s in range(rows_total.bit_length())):
+                tiles = (bu, bka, bnb, bna, kbp)
+                vmem = fused_vmem_bytes(*tiles, itemsize, accum)
+                if vmem > vmem_budget:
+                    break  # a larger bu only grows the footprint
+                steps = _pad_up(rows_total, bu) // bu * n_j * t_b * t_a
+                cost = (_fused_hbm_bytes(rows_total, na, ka, tiles, n_j * t_a,
+                                         t_b, itemsize) / HBM_BYTES_PER_S
+                        + steps * GRID_STEP_S)
+                if best is None or (cost, vmem) < best[:2]:
+                    best = (cost, vmem, tiles)
+    return None if best is None else best[2]
 
 
 def fused3_vmem_bytes(bu: int, bka: int, bnb: int, bnc: int, bna: int,
@@ -853,22 +901,32 @@ def staged_pair_hbm_bytes(stage_a: StagePlan, stage_b: StagePlan,
             + stage_hbm_bytes(stage_b, batch, itemsize))
 
 
-def _fused_hbm_bytes(rows_total: int, ka: int,
+def _fused_hbm_bytes(rows_total: int, na: int, ka: int,
                      tiles: tuple[int, int, int, int, int],
                      live_a: int, live_b: int, itemsize: int) -> int:
     """Modeled HBM traffic of the fused kernel (dense grid × live blocks).
 
     X and C_a are fetched once per live ``(j, t_b, t_a)`` step and u-block;
     C_b once per live slab and (i, j); the intermediate moves zero bytes.
+    The Pallas pipeline fetches a block only when its index changes
+    between consecutive grid steps, so a block whose index axes all have
+    extent 1 inside the loop is fetched once: X once per u-block when the
+    na and nb sweeps are one block each, C_a once per (i, j) when the na
+    sweep is one block (once in all when Ka is one block too), and C_b
+    once in all when it is one live slab.
     """
     bu, bka, bnb, bna, kbp = tiles
     u_p = _pad_up(rows_total, bu)
-    ka_p = _pad_up(ka, bka)
+    n_i, n_j = u_p // bu, _pad_up(ka, bka) // bka
+    one_na = _pad_up(na, bna) == bna
     t_b = max(live_b, 1)
-    x_bytes = u_p * bnb * bna * live_a * t_b
-    ca_bytes = (u_p // bu) * t_b * live_a * bna * bka
-    cb_bytes = (u_p // bu) * (ka_p // bka) * t_b * bnb * kbp
-    y_bytes = u_p * ka_p * kbp
+    x_bytes = u_p * bnb * bna * (1 if one_na and t_b == 1
+                                 else live_a * t_b)
+    ca_visits = (n_i * t_b * live_a if not one_na
+                 else n_i * live_a if n_j > 1 else 1)
+    ca_bytes = ca_visits * bna * bka
+    cb_bytes = (n_i * n_j * t_b if t_b > 1 else 1) * bnb * kbp
+    y_bytes = u_p * n_j * bka * kbp
     return (x_bytes + ca_bytes + cb_bytes + y_bytes) * itemsize
 
 
@@ -923,8 +981,8 @@ def refresh_fused_pair(fp: FusedPairPlan, ca: jnp.ndarray, cb: jnp.ndarray,
     return dataclasses.replace(
         fp,
         vmem_bytes=fused_vmem_bytes(*tiles, itemsize, fp.accum),
-        hbm_bytes_fused=_fused_hbm_bytes(rows_total, fp.ka, tiles, live_a,
-                                         live_b, itemsize),
+        hbm_bytes_fused=_fused_hbm_bytes(rows_total, fp.na, fp.ka, tiles,
+                                         live_a, live_b, itemsize),
         zero_block_frac_a=1.0 - live_a / dense_a,
         zero_block_frac_b=1.0 - live_b / dense_b,
     )
@@ -1159,7 +1217,7 @@ def _plan_fusion(
         mask_b = np.asarray(_padded_block_mask(cb, bnb, kbp))
         live_a, dense_a = int(mask_a.sum()), max(mask_a.size, 1)
         live_b, dense_b = int(mask_b.sum()), max(mask_b.size, 1)
-        fused = _fused_hbm_bytes(rows_total, ka, tiles, live_a, live_b,
+        fused = _fused_hbm_bytes(rows_total, na, ka, tiles, live_a, live_b,
                                  itemsize)
         cand = FusedPairPlan(
             first=first, mode_a=mode_a, mode_b=mode_b, rows=rows,
@@ -1324,7 +1382,7 @@ def chain3_tile_sizes(
             tiles["bna"], kbp, kcp)
 
 
-def _chain_hbm_bytes(rows_total: int, ka: int, nb: int,
+def _chain_hbm_bytes(rows_total: int, na: int, ka: int, nb: int,
                      tiles: tuple[int, int, int, int, int],
                      live_a: int, itemsize: int) -> int:
     """Modeled HBM traffic of the chain-pair kernel.
@@ -1339,7 +1397,8 @@ def _chain_hbm_bytes(rows_total: int, ka: int, nb: int,
     u_p = _pad_up(rows_total, bu)
     ka_p = _pad_up(ka, bka)
     y1_bytes = u_p * t_b * bnb * ka_p * itemsize
-    return (_fused_hbm_bytes(rows_total, ka, tiles, live_a, t_b, itemsize)
+    return (_fused_hbm_bytes(rows_total, na, ka, tiles, live_a, t_b,
+                             itemsize)
             + y1_bytes)
 
 
@@ -1516,7 +1575,7 @@ def plan_adjoint_chain(
                 "vmem_budget": vmem_budget,
             })
             return declined(events)
-        fused_bytes = (_chain_hbm_bytes(rows2, a0.k, a1.n, t2,
+        fused_bytes = (_chain_hbm_bytes(rows2, a0.n, a0.k, a1.n, t2,
                                         live_a_blocks(a0, t2[3], t2[1]),
                                         itemsize)
                        + stage_hbm_bytes(a2, batch, itemsize))

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 from repro.core import coefficient_matrix, dxt3d, gemt3
 from repro.engine import (AutotuneCache, autotune_fused, build_plan,
                           fused_tile_sizes, fused_vmem_bytes, gemt3_planned)
+from repro.engine.plan import DEFAULT_VMEM_BUDGET, _plan_fusion
 from repro.kernels import ops
 
 RNG = np.random.default_rng(17)
@@ -274,9 +275,18 @@ class TestFusionDecision:
         plan = build_plan((64, 48, 256), jnp.float32, c1, c2, c3, fuse="pair",
                           block_sizes=(128, 64, 128))
         assert plan.fused is not None
-        assert plan.fused.mode_a == 3
-        assert plan.fused.zero_block_frac_a == pytest.approx(0.5)
-        assert plan.fused.zero_block_frac_b == 0.0
+        # The pair holding C3 (the dense pair of modes 1, 2 may save more
+        # bytes, and then it is the one the plan runs).
+        first = min(plan.order.index(3), 1)
+        fp = _plan_fusion(first, plan.order, plan.stages, (64, 48, 256),
+                          {1: c1, 2: c2, 3: c3}, batch=1, itemsize=4,
+                          vmem_budget=DEFAULT_VMEM_BUDGET, force=False)
+        assert fp is not None
+        assert fp.mode_a == 3
+        assert fp.zero_block_frac_a == pytest.approx(0.5)
+        assert fp.zero_block_frac_b == 0.0
+        assert (plan.fused.hbm_bytes_staged - plan.fused.hbm_bytes_fused
+                >= fp.hbm_bytes_staged - fp.hbm_bytes_fused)
 
     def test_key_distinguishes_fusion_options(self):
         shape, cs = self._serving()
